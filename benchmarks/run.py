@@ -47,7 +47,8 @@ def _bench_kernels() -> int:
     ip, w = g.prepare(img)
     ipj, wj = jnp.asarray(ip), jnp.asarray(w)
     us = timeit(lambda: g.run_range(ipj, wj, 0, g.total_work(img)))
-    pal = g.run_range(ipj, wj, 0, 1, use_pallas=True)
+    from repro.kernels.gaussian import kernel as gk
+    pal = gk.blur_rows(ipj[:g.LWS + g.KSIZE - 1], wj)
     ref = g.run_range(ipj, wj, 0, 1)
     ok = bool(jnp.allclose(pal, ref, atol=1e-4))
     rows.append(("kernel_gaussian", us, f"pallas_allclose={ok}"))
@@ -55,7 +56,8 @@ def _bench_kernels() -> int:
     from repro.kernels.binomial import ops as b
     s0, k0, ty = map(jnp.asarray, b.make_inputs(16384))
     us = timeit(lambda: b.run_range(s0, k0, ty, 0, b.total_work(16384)))
-    pal = b.run_range(s0, k0, ty, 0, 1, use_pallas=True)
+    from repro.kernels.binomial import kernel as bk
+    pal = bk.price_options(s0[:b.LWS], k0[:b.LWS], ty[:b.LWS], steps=b.STEPS)
     ref = b.run_range(s0, k0, ty, 0, 1)
     ok = bool(jnp.allclose(pal, ref, atol=1e-3))
     rows.append(("kernel_binomial", us, f"pallas_allclose={ok}"))
@@ -63,8 +65,8 @@ def _bench_kernels() -> int:
     from repro.kernels.mandelbrot import ops as m
     us = timeit(lambda: m.run_range(0, m.total_work(256), width=256,
                                     height=256, max_iter=256))
-    pal = m.run_range(0, 1, width=256, height=256, max_iter=64,
-                      use_pallas=True)
+    from repro.kernels.mandelbrot import kernel as mk
+    pal = mk.escape_counts(0, m.LWS, 256, 256, 64)
     ref = m.run_range(0, 1, width=256, height=256, max_iter=64)
     ok = bool((pal == ref).all())
     rows.append(("kernel_mandelbrot", us, f"pallas_exact={ok}"))
@@ -72,9 +74,10 @@ def _bench_kernels() -> int:
     from repro.kernels.nbody import ops as n
     pm, vel = map(jnp.asarray, n.make_inputs(4096))
     us = timeit(lambda: n.run_range(pm, vel, 0, n.total_work(4096)))
-    pal = n.run_range(pm, vel, 0, 2, use_pallas=True)
-    ref = n.run_range(pm, vel, 0, 2)
-    ok = bool(jnp.allclose(pal, ref, rtol=1e-4, atol=1e-4))
+    from repro.kernels.nbody import kernel as nk, ref as nr
+    pal = nk.accelerations(pm[:2 * n.LWS], pm)
+    ref = nr.accelerations(pm, 0, 2 * n.LWS)
+    ok = bool(jnp.allclose(pal, ref, rtol=2e-4, atol=2e-4))
     rows.append(("kernel_nbody", us, f"pallas_allclose={ok}"))
 
     from repro.kernels.ray import ops as r, ref as rr

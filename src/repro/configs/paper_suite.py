@@ -88,6 +88,16 @@ BENCHES: Dict[str, BenchSpec] = {
                             irregularity=_mandel_irr, regular=False),
 }
 
+# Table I problem sizes, as keyword arguments of the program adapters in
+# core/programs.py (the real-execution counterpart of BENCHES)
+PAPER_SIZES: Dict[str, Dict[str, int]] = {
+    "gaussian": dict(h=8192, w=8192),           # 8192px image, 31px filter
+    "binomial": dict(n_options=4194304),        # 4194304 samples
+    "mandelbrot": dict(px=14336, max_iter=5000),
+    "nbody": dict(n_bodies=229376),
+    "ray1": dict(px=4096),
+}
+
 DEVICE_NAMES = ("cpu", "igpu", "gpu")
 
 # offline-profiling bias per device: what the scheduler's static profile

@@ -45,6 +45,11 @@ class DeviceGroup:
     throughput: Optional[float] = None    # work-groups / s (EWMA)
     dead: bool = False
 
+    @property
+    def platform(self) -> str:
+        """Platform the group's packets run on ("tpu", "cpu", ...)."""
+        return (self.device or jax.devices()[0]).platform
+
     def put(self, x):
         if self.device is None:
             return x

@@ -11,12 +11,16 @@ Two geometries per the Region redesign:
   schedulers carve row panels.  These are the ROI-offloading targets
   (register once, re-submit sub-regions warm).
 
-Sizes are scaled down from the paper's (which target a ~2 s GTX 950 run)
-so the real-execution benches stay fast on one CPU; the simulator
-(configs/paper_suite.py) carries the full calibrated sizes."""
+Each build picks its kernel from the device group's platform: the Pallas
+kernel, compiled, on a TPU group; the jnp path on any other (``ray`` has
+no Pallas kernel: its jnp path is its implementation).
+
+Default sizes are scaled down from the paper's (which target a ~2 s GTX
+950 run) so the real-execution benches stay fast on one CPU;
+``configs/paper_suite.PAPER_SIZES`` holds the paper's own sizes."""
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -31,8 +35,12 @@ from repro.kernels.ray import ops as ray_ops
 from repro.kernels.ray import ref as ray_ref
 
 
-def gaussian_program(h: int = 1024, w: int = 512, seed: int = 0,
-                     use_pallas: bool = False) -> Program:
+def _use_pallas(dev) -> bool:
+    """The Pallas kernel runs on TPU groups; every other group runs jnp."""
+    return dev.platform == "tpu"
+
+
+def gaussian_program(h: int = 1024, w: int = 512, seed: int = 0) -> Program:
     rng = np.random.default_rng(seed)
     img = rng.standard_normal((h, w)).astype(np.float32)
     ip, wts = gaussian_ops.prepare(img)
@@ -41,6 +49,7 @@ def gaussian_program(h: int = 1024, w: int = 512, seed: int = 0,
     def build(dev):
         ipd = dev.put(jnp.asarray(ip))
         wd = dev.put(jnp.asarray(wts))
+        use_pallas = _use_pallas(dev)
 
         def fn(offset, size):
             return gaussian_ops.run_range(ipd, wd, offset, size,
@@ -62,10 +71,12 @@ def gaussian_program_2d(h: int = 512, w: int = 512, seed: int = 0,
     def build(dev):
         ipd = dev.put(jnp.asarray(ip))
         wd = dev.put(jnp.asarray(wts))
+        use_pallas = _use_pallas(dev)
 
         def fn(row0, n_rows, col0, n_cols):
             return gaussian_ops.run_region(ipd, wd, row0, n_rows,
-                                           col0, n_cols)
+                                           col0, n_cols,
+                                           use_pallas=use_pallas)
         return fn
 
     return Program("gaussian2d", build=build,
@@ -76,10 +87,14 @@ def gaussian_program_2d(h: int = 512, w: int = 512, seed: int = 0,
 def mandelbrot_program_2d(px: int = 256, max_iter: int = 256,
                           lws: Tuple[int, int] = (8, 8)) -> Program:
     def build(dev):
+        use_pallas = _use_pallas(dev)
+
         def fn(row0, n_rows, col0, n_cols):
             return mandelbrot_ops.run_region(row0, n_rows, col0, n_cols,
                                              width=px, height=px,
-                                             max_iter=max_iter)
+                                             max_iter=max_iter,
+                                             use_pallas=use_pallas,
+                                             device=dev.device)
         return fn
 
     return Program("mandelbrot2d", build=build,
@@ -104,13 +119,13 @@ def ray_program_2d(which: int = 1, px: int = 256,
                    in_bytes=sum(v.nbytes for v in scene.values()))
 
 
-def binomial_program(n_options: int = 65536, seed: int = 0,
-                     use_pallas: bool = False) -> Program:
+def binomial_program(n_options: int = 65536, seed: int = 0) -> Program:
     s0, k0, ty = binomial_ops.make_inputs(n_options, seed)
     G = binomial_ops.total_work(n_options)
 
     def build(dev):
         a, b, c = (dev.put(jnp.asarray(x)) for x in (s0, k0, ty))
+        use_pallas = _use_pallas(dev)
 
         def fn(offset, size):
             return binomial_ops.run_range(a, b, c, offset, size,
@@ -122,15 +137,16 @@ def binomial_program(n_options: int = 65536, seed: int = 0,
                    in_bytes=s0.nbytes + k0.nbytes + ty.nbytes)
 
 
-def mandelbrot_program(px: int = 512, max_iter: int = 256,
-                       use_pallas: bool = False) -> Program:
+def mandelbrot_program(px: int = 512, max_iter: int = 256) -> Program:
     G = mandelbrot_ops.total_work(px)
 
     def build(dev):
+        use_pallas = _use_pallas(dev)
+
         def fn(offset, size):
             return mandelbrot_ops.run_range(
                 offset, size, width=px, height=px, max_iter=max_iter,
-                use_pallas=use_pallas)
+                use_pallas=use_pallas, device=dev.device)
         return fn
 
     return Program("mandelbrot", G, 1, build,
@@ -138,14 +154,14 @@ def mandelbrot_program(px: int = 512, max_iter: int = 256,
                    out_dtype=np.int32)
 
 
-def nbody_program(n_bodies: int = 8192, seed: int = 0,
-                  use_pallas: bool = False) -> Program:
+def nbody_program(n_bodies: int = 8192, seed: int = 0) -> Program:
     pm, vel = nbody_ops.make_inputs(n_bodies, seed)
     G = nbody_ops.total_work(n_bodies)
 
     def build(dev):
         pmd = dev.put(jnp.asarray(pm))
         vd = dev.put(jnp.asarray(vel))
+        use_pallas = _use_pallas(dev)
 
         def fn(offset, size):
             return nbody_ops.run_range(pmd, vd, offset, size,
@@ -189,21 +205,39 @@ PROGRAMS = {
 }
 
 
-class _HostDev:
+class _ReferenceDev:
+    """Build target of the reference: the jnp path on the default device."""
+    device = None
+    platform = None
+
     def put(self, x):
         return x
 
 
-def reference_output(program_name: str, **kwargs) -> np.ndarray:
-    """Single-device single-packet execution (the correctness oracle for
-    co-executed outputs).  2-D programs return (rows, cols*out_cols)."""
+def reference_output(program_name: str, packet: Optional[int] = None,
+                     **kwargs) -> np.ndarray:
+    """Single-device execution of the jnp path (the correctness oracle for
+    co-executed outputs).  ``packet`` bounds the dim-0 work-groups per call
+    (default: the whole NDRange in one call); a large program at the
+    paper's sizes needs it to fit the device.  2-D programs return
+    (rows, cols*out_cols)."""
     prog = PROGRAMS[program_name](**kwargs)
-    fn = prog.build(_HostDev())
+    fn = prog.build(_ReferenceDev())
     region = prog.work_region
+    d0 = region.dims[0]
+    step = packet or d0.size
+    parts = []
+    for off in range(d0.offset, d0.offset + d0.size, step):
+        size = min(step, d0.offset + d0.size - off)
+        if region.ndim == 2:
+            d1 = region.dims[1]
+            out = fn(off, size, d1.offset, d1.size)
+        else:
+            out = fn(off, size)
+        parts.append(np.asarray(out).reshape(size * prog.out_rows_per_wg,
+                                             -1))
+    out = np.concatenate(parts)
     if region.ndim == 2:
-        d0, d1 = region.dims
-        out = np.asarray(fn(d0.offset, d0.size, d1.offset, d1.size))
         return out.reshape(d0.size * prog.out_rows_per_wg,
-                           d1.size * prog.out_cols)
-    out = np.asarray(fn(0, prog.total_work))
+                           region.dims[1].size * prog.out_cols)
     return out.reshape(prog.total_work * prog.out_rows_per_wg, prog.out_cols)

@@ -2,53 +2,65 @@
 
 TPU adaptation: OpenCL maps one option per work-group and one tree level
 per 255-work-item local array with barriers between backward-induction
-steps.  On TPU the whole (tile_opts, steps+1) value plane lives in VMEM and
-each induction step is one fused VPU op over the plane — barriers become
-data flow.  tile=128 options x 256 levels x 4B = 128 KiB VMEM."""
+steps.  On TPU a grid step prices ``tile`` options at once: options lie on
+the lanes and the tree levels on the sublanes of one (256, tile) value
+plane, and each induction step is one fused VPU update of the plane —
+barriers become data flow.  The step's ``v[j + 1]`` is a sublane rotation
+of the plane; the rotation wraps level 255 into level 254, but level 0
+after ``steps`` updates reads only levels 0..steps, so the wrapped values
+never reach the price.  The per-option tree constants come in from
+``ref.tree_coefficients``, the jnp path's own code.
+tile=128 options x 256 levels x 4 B = 128 KiB."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.binomial.ref import RISKFREE, VOLATILITY
+from repro.kernels import resolve_interpret
+from repro.kernels.binomial.ref import tree_coefficients
 
 
-def _binomial_kernel(s0_ref, strike_ref, ty_ref, out_ref, *, steps: int):
-    s0 = s0_ref[...]
+def _binomial_kernel(s0_ref, strike_ref, vdt_ref, pu_ref, pd_ref, disc_ref,
+                     out_ref, *, steps: int, levels: int):
+    s0 = s0_ref[...]                          # (1, tile)
     strike = strike_ref[...]
-    ty = ty_ref[...]
-    dt = ty / steps
-    vdt = VOLATILITY * jnp.sqrt(dt)
-    u_minus_d = jnp.exp(vdt) - jnp.exp(-vdt)
-    a = jnp.exp(RISKFREE * dt)
-    pu = (a - jnp.exp(-vdt)) / u_minus_d
-    pd = 1.0 - pu
-    disc = jnp.exp(-RISKFREE * dt)
-    j = jnp.arange(steps + 1, dtype=jnp.float32)
-    sT = s0[:, None] * jnp.exp(vdt[:, None] * (2.0 * j[None, :] - steps))
-    v = jnp.maximum(sT - strike[:, None], 0.0)
+    vdt = vdt_ref[...]
+    pu = pu_ref[...]
+    pd = pd_ref[...]
+    disc = disc_ref[...]
+    shape = (levels, s0.shape[1])
+    j = jax.lax.broadcasted_iota(jnp.int32, shape, 0).astype(jnp.float32)
+    sT = s0 * jnp.exp(vdt * (2.0 * j - steps))
+    v = jnp.maximum(sT - strike, 0.0)
 
-    def body(i, v):
-        vn = disc[:, None] * (pd[:, None] * v[:, :-1] + pu[:, None] * v[:, 1:])
-        return jnp.concatenate([vn, v[:, -1:]], axis=1)
+    def body(_, v):
+        up = pltpu.roll(v, levels - 1, 0)     # up[j] = v[j + 1]
+        return disc * (pd * v + pu * up)
 
     v = jax.lax.fori_loop(0, steps, body, v)
-    out_ref[...] = v[:, 0]
+    out_ref[...] = v[0:1, :]
 
 
 def price_options(s0, strike, t_years, *, steps: int = 254,
-                  tile: int = 128, interpret: bool = True):
+                  tile: int = 128, interpret: Optional[bool] = None):
+    """s0/strike/t_years: (n,) -> (n,) option values; n % tile == 0."""
     n = s0.shape[0]
     assert n % tile == 0, (n, tile)
-    kernel = functools.partial(_binomial_kernel, steps=steps)
-    return pl.pallas_call(
+    levels = -(-(steps + 1) // 8) * 8
+    kernel = functools.partial(_binomial_kernel, steps=steps, levels=levels)
+    rows = (s0, strike, *tree_coefficients(t_years, steps=steps))
+    spec = pl.BlockSpec((1, tile), lambda i: (0, i))
+    out = pl.pallas_call(
         kernel,
         grid=(n // tile,),
-        in_specs=[pl.BlockSpec((tile,), lambda i: (i,))] * 3,
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        interpret=interpret,
-    )(s0, strike, t_years)
+        in_specs=[spec] * len(rows),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(*(r.reshape(1, n) for r in rows))
+    return out.reshape(n)

@@ -6,7 +6,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.binomial import kernel as K
@@ -24,9 +23,8 @@ def make_inputs(n_options: int, seed: int = 0):
     return s0, strike, ty
 
 
-@partial(jax.jit, static_argnames=("size", "use_pallas", "interpret"))
-def _run(s0, strike, ty, offset, *, size: int, use_pallas: bool = False,
-         interpret: bool = True):
+@partial(jax.jit, static_argnames=("size", "use_pallas"))
+def _run(s0, strike, ty, offset, *, size: int, use_pallas: bool):
 
     def sl(x):
         return jax.lax.dynamic_slice(x, (offset,), (size,))
@@ -34,14 +32,16 @@ def _run(s0, strike, ty, offset, *, size: int, use_pallas: bool = False,
     a, b, c = sl(s0), sl(strike), sl(ty)
     if use_pallas:
         return K.price_options(a, b, c, steps=STEPS, tile=min(128, size),
-                               interpret=interpret)
+                               interpret=False)
     return R.price_options(a, b, c, steps=STEPS)
 
 
 def run_range(s0, strike, ty, offset: int, size: int, *,
-              use_pallas: bool = False, interpret: bool = True):
-    return _run(s0, strike, ty, jnp.int32(offset * LWS), size=size * LWS,
-                use_pallas=use_pallas, interpret=interpret)
+              use_pallas: bool = False):
+    """Price options [offset*LWS, (offset+size)*LWS).  ``use_pallas`` picks
+    the compiled Pallas kernel (TPU only) over the jnp path."""
+    return _run(s0, strike, ty, offset * LWS, size=size * LWS,
+                use_pallas=use_pallas)
 
 
 def total_work(n_options: int) -> int:

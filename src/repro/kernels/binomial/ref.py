@@ -14,8 +14,12 @@ RISKFREE = 0.02
 VOLATILITY = 0.30
 
 
-def price_options(s0, strike, t_years, *, steps: int = STEPS):
-    """s0/strike/t_years: (n,) arrays -> (n,) option values."""
+def tree_coefficients(t_years, *, steps: int = STEPS):
+    """Per-option tree constants (vdt, pu, pd, disc), each shaped like
+    ``t_years``.  The price's error grows about ``steps`` times faster than
+    ``pu``'s (it shifts the mean of a ``steps``-trial binomial), so the
+    Pallas kernel takes these from here rather than recomputing them with
+    its own exp and divide."""
     dt = t_years / steps
     vdt = VOLATILITY * jnp.sqrt(dt)
     u = jnp.exp(vdt)
@@ -24,6 +28,12 @@ def price_options(s0, strike, t_years, *, steps: int = STEPS):
     pu = (a - d) / (u - d)
     pd = 1.0 - pu
     disc = jnp.exp(-RISKFREE * dt)
+    return vdt, pu, pd, disc
+
+
+def price_options(s0, strike, t_years, *, steps: int = STEPS):
+    """s0/strike/t_years: (n,) arrays -> (n,) option values."""
+    vdt, pu, pd, disc = tree_coefficients(t_years, steps=steps)
     j = jnp.arange(steps + 1, dtype=jnp.float32)
     # leaf prices: S * u^j * d^(steps-j)
     sT = s0[:, None] * jnp.exp(vdt[:, None] * (2.0 * j[None, :] - steps))

@@ -16,11 +16,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -67,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B,S,H,D); k,v: (B,S,KH,D), causal. S % bq == 0 == S % bk."""
     B, S, H, D = q.shape
     KH = k.shape[2]
@@ -98,7 +101,7 @@ def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128,
             pltpu.VMEM((bq * G, 1), jnp.float32),
             pltpu.VMEM((bq * G, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qr, kr, vr)
     out = out.reshape(B, KH, S, G, D)
     return jnp.moveaxis(out, 2, 1).reshape(B, S, H, D)

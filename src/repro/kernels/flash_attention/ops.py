@@ -4,6 +4,7 @@ portable model stack (models/layers.py)."""
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 
@@ -13,8 +14,8 @@ from repro.models.layers import blocked_causal_attention
 
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret", "chunk"))
-def attention(q, k, v, *, use_pallas: bool = False, interpret: bool = True,
-              chunk: int = 2048):
+def attention(q, k, v, *, use_pallas: bool = False,
+              interpret: Optional[bool] = None, chunk: int = 2048):
     if use_pallas:
         return K.flash_attention(q, k, v, interpret=interpret)
     return blocked_causal_attention(q, k, v, chunk)

@@ -14,11 +14,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -61,7 +64,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
 
 def flash_decode(q, k_cache, v_cache, pos, *, bk: int = 512,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """q: (B,H,D); caches: (B,S,KH,D) in storage dtype; pos: () int32."""
     B, H, D = q.shape
     S, KH = k_cache.shape[1], k_cache.shape[2]
@@ -90,6 +93,6 @@ def flash_decode(q, k_cache, v_cache, pos, *, bk: int = 512,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pos_arr, qr, kr, vr)
     return out.reshape(B, H, D)

@@ -4,6 +4,7 @@ used by models/layers.py::cached_decode_attention."""
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 
@@ -14,7 +15,7 @@ from repro.models.layers import cached_decode_attention
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret", "bk"))
 def decode_attention(q, k_cache, v_cache, pos, *, use_pallas: bool = False,
-                     interpret: bool = True, bk: int = 512):
+                     interpret: Optional[bool] = None, bk: int = 512):
     """q: (B,H,D); caches: (B,S,KH,D); pos: () -> (B,H,D)."""
     if use_pallas:
         return K.flash_decode(q, k_cache, v_cache, pos, bk=bk,

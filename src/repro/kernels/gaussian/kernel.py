@@ -1,61 +1,76 @@
-"""Pallas TPU kernel: separable Gaussian blur, row-tile blocked.
+"""Pallas TPU kernel: separable Gaussian blur, tiled in rows and columns.
 
 TPU adaptation (vs the OpenCL per-pixel NDRange): one grid step produces a
-``tile_h x W`` row band.  The vertical pass needs a K-1 row halo; Pallas
-blocks are non-overlapping, so the kernel takes the padded image twice —
-block i ("cur") and block i+1 ("nxt") — and assembles the
-``tile_h + K - 1`` band in VMEM (requires K - 1 <= tile_h, true for the
-paper's 31px filter with tile_h = 64).  The horizontal pass slides within
-the band with static slices => unrolled VPU vector ops.  VMEM working set:
-2 * tile_h * (W + K - 1) * 4B ≈ 4.2 MiB at W = 8192.
+``tile_h x tile_w`` output tile.  The filter needs a K-1 halo below and to
+the right of the tile; Pallas blocks do not overlap, so the kernel reads
+the padded image four times — the tile itself, an ``hr``-row strip below
+it, an ``hc``-column strip to its right and the ``hr x hc`` corner (``hr``
+and ``hc`` are K-1 rounded up to the (8, 128) tiling) — and assembles the
+``(tile_h + hr) x (tile_w + hc)`` band in VMEM.  Both passes slide within
+the band with static slices => unrolled VPU vector ops.  The 1-D weights
+sit in SMEM.  Scoped VMEM at the defaults (64 x 512 tile, 31-tap filter):
+the double-buffered input and output blocks (~0.8 MiB) plus the band,
+vertical-pass and output values (~0.6 MiB), whatever the image width.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
-def _blur_kernel(cur_ref, nxt_ref, w_ref, out_ref, *, K: int, tile_h: int):
-    cur = cur_ref[...]                       # (tile_h, Wp)
-    nxt = nxt_ref[...]                       # (tile_h, Wp)
-    w = w_ref[...]                           # (K,)
-    band = jnp.concatenate([cur, nxt[:K - 1, :]], axis=0)
-    Wout = out_ref.shape[1]
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _blur_kernel(cur_ref, below_ref, right_ref, corner_ref, w_ref, out_ref,
+                 *, K: int):
+    tile_h, tile_w = out_ref.shape
+    band = jnp.concatenate([
+        jnp.concatenate([cur_ref[...], right_ref[...]], axis=1),
+        jnp.concatenate([below_ref[...], corner_ref[...]], axis=1)], axis=0)
     tmp = jnp.zeros((tile_h, band.shape[1]), jnp.float32)
     for k in range(K):                       # vertical pass (static unroll)
-        tmp = tmp + w[k] * band[k:k + tile_h, :]
-    out = jnp.zeros((tile_h, Wout), jnp.float32)
+        tmp = tmp + w_ref[k] * band[k:k + tile_h, :]
+    out = jnp.zeros((tile_h, tile_w), jnp.float32)
     for k in range(K):                       # horizontal pass
-        out = out + w[k] * tmp[:, k:k + Wout]
+        out = out + w_ref[k] * tmp[:, k:k + tile_w]
     out_ref[...] = out
 
 
-def blur_rows(img_padded, w1d, *, tile_h: int = 64, interpret: bool = True):
+def blur_rows(img_padded, w1d, *, tile_h: int = 64, tile_w: int = 512,
+              interpret: Optional[bool] = None):
     """img_padded: (H + K - 1, W + K - 1) with edge padding; returns (H, W).
-    H must be a multiple of tile_h and K - 1 <= tile_h."""
+    Any H and W: the image is zero-extended to whole tiles and the result
+    cropped."""
     K = w1d.shape[0]
     Hp, Wp = img_padded.shape
     H, W = Hp - (K - 1), Wp - (K - 1)
-    assert H % tile_h == 0, (H, tile_h)
-    assert K - 1 <= tile_h, (K, tile_h)
-    n = H // tile_h
-    # room for the "next" view of the final tile: pad rows to (n + 1) tiles
-    extra = (n + 1) * tile_h - Hp
-    imgp = jnp.pad(img_padded, ((0, max(extra, 0)), (0, 0)))
-    grid = (n,)
-    kernel = functools.partial(_blur_kernel, K=K, tile_h=tile_h)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    hr = _round_up(max(K - 1, 1), 8)         # halo rows, sublane-aligned
+    hc = _round_up(max(K - 1, 1), 128)       # halo columns, lane-aligned
+    tile_h = _round_up(min(tile_h, H), hr)
+    tile_w = _round_up(min(tile_w, W), hc)
+    Ho, Wo = _round_up(H, tile_h), _round_up(W, tile_w)
+    img = jnp.pad(img_padded, ((0, Ho + hr - Hp), (0, Wo + hc - Wp)))
+    rh, rw = tile_h // hr, tile_w // hc      # tile size in halo blocks
+    out = pl.pallas_call(
+        functools.partial(_blur_kernel, K=K),
+        grid=(Ho // tile_h, Wo // tile_w),
         in_specs=[
-            pl.BlockSpec((tile_h, Wp), lambda i: (i, 0)),       # cur band
-            pl.BlockSpec((tile_h, Wp), lambda i: (i + 1, 0)),   # halo band
-            pl.BlockSpec((K,), lambda i: (0,)),
+            pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j)),
+            pl.BlockSpec((hr, tile_w), lambda i, j: ((i + 1) * rh, j)),
+            pl.BlockSpec((tile_h, hc), lambda i, j: (i, (j + 1) * rw)),
+            pl.BlockSpec((hr, hc), lambda i, j: ((i + 1) * rh, (j + 1) * rw)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((tile_h, W), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((H, W), jnp.float32),
-        interpret=interpret,
-    )(imgp, imgp, w1d)
+        out_specs=pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Ho, Wo), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(img, img, img, img, w1d)
+    return out[:H, :W]

@@ -14,11 +14,14 @@ ds=16.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _scan_kernel(a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
@@ -53,7 +56,7 @@ def _scan_kernel(a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
 
 
 def selective_scan(a, b, C, *, chunk: int = 64, tile_d: int = 512,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """a,b: (B,S,di,ds) f32; C: (B,S,ds) f32 -> (y (B,S,di), h (B,di,ds))."""
     B, S, di, ds = a.shape
     chunk = min(chunk, S)
@@ -82,7 +85,7 @@ def selective_scan(a, b, C, *, chunk: int = 64, tile_d: int = 512,
             jax.ShapeDtypeStruct((B, di, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((tile_d, ds), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
         if hasattr(pltpu, "CompilerParams") else None,

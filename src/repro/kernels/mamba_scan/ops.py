@@ -3,6 +3,7 @@ chunked associative-scan jnp path used by the portable model stack."""
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +15,7 @@ from repro.models.layers import _ssm_scan_chunked
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret", "chunk"))
 def selective_scan(a, b, C, *, use_pallas: bool = False,
-                   interpret: bool = True, chunk: int = 128):
+                   interpret: Optional[bool] = None, chunk: int = 128):
     if use_pallas:
         return K.selective_scan(a, b, C, chunk=min(chunk, 64),
                                 interpret=interpret)

@@ -1,35 +1,35 @@
-"""Pallas TPU kernel: Mandelbrot escape iterations, row-tile blocked.
+"""Pallas TPU kernel: Mandelbrot escape iterations, tiled in rows and columns.
 
 TPU adaptation: the OpenCL kernel is one work-item per pixel with early
 exit; SIMD lanes on the VPU can't exit early, so the kernel runs the fixed
-``max_iter`` loop over a (tile_h, W) VMEM tile with a liveness mask — the
-exact shape a TPU vector unit wants.  The irregularity the paper exploits
-(work varies per region) survives at packet granularity: rows in the
-needle/bulb region cost the full 5000 iterations in every lane, edge rows
-exit the mask early (the `alive` popcount drops but the loop is fixed —
-cost becomes uniform per packet, which is FASTER and is recorded in
-DESIGN.md as a TPU-vs-GPU behavioural difference; the co-execution figures
-model the GPU-style early-exit cost profile in the simulator)."""
+``max_iter`` loop over a (tile_h, tile_w) tile with a liveness mask — the
+exact shape a TPU vector unit wants, small enough that the loop state stays
+in vector registers.  The pixel coordinates come in as a (1, tile_w) row of
+real parts and a (tile_h, 1) column of imaginary parts, computed by the jnp
+path's own ``ref.pixel_centres``, so one compiled kernel serves every
+packet of a shape and counts exactly as the jnp path does.  The
+irregularity the paper exploits (work varies per region) is flattened
+here: every pixel pays all ``max_iter`` iterations, so a packet's cost
+depends only on its size.  This is a TPU-vs-GPU behavioural difference;
+the co-execution figures model the GPU-style early-exit cost profile in
+the simulator (configs/paper_suite.py)."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.mandelbrot.ref import X0, X1, Y0, Y1
+from repro.kernels import resolve_interpret
+from repro.kernels.mandelbrot.ref import pixel_centres
 
 
-def _mandel_kernel(row0_ref, out_ref, *, width: int, height: int,
-                   tile_h: int, max_iter: int):
-    i = pl.program_id(0)
-    row0 = row0_ref[0] + i * tile_h
-    ys = Y0 + (Y1 - Y0) * (jnp.arange(tile_h, dtype=jnp.float32)
-                           + row0.astype(jnp.float32) + 0.5) / height
-    xs = X0 + (X1 - X0) * (jnp.arange(width, dtype=jnp.float32) + 0.5) / width
-    cr = jnp.broadcast_to(xs[None, :], (tile_h, width))
-    ci = jnp.broadcast_to(ys[:, None], (tile_h, width))
+def _mandel_kernel(cr_ref, ci_ref, out_ref, *, max_iter: int):
+    tile_h, tile_w = out_ref.shape
+    cr = jnp.broadcast_to(cr_ref[...], (tile_h, tile_w))
+    ci = jnp.broadcast_to(ci_ref[...], (tile_h, tile_w))
 
     def body(_, st):
         zr, zi, cnt = st
@@ -39,25 +39,34 @@ def _mandel_kernel(row0_ref, out_ref, *, width: int, height: int,
         new_zi = jnp.where(alive, 2 * zr * zi + ci, zi)
         return new_zr, new_zi, cnt + alive.astype(jnp.int32)
 
-    zr = jnp.zeros((tile_h, width), jnp.float32)
-    zi = jnp.zeros((tile_h, width), jnp.float32)
-    cnt = jnp.zeros((tile_h, width), jnp.int32)
-    _, _, cnt = jax.lax.fori_loop(0, max_iter, body, (zr, zi, cnt))
-    out_ref[...] = cnt
+    # the first iteration is peeled: from z = 0 every pixel is alive and
+    # z becomes c exactly.  Starting the carry from c (not from a constant
+    # zero splat) also gives Mosaic a loop-carry layout it can keep.
+    cnt = jnp.ones((tile_h, tile_w), jnp.int32)
+    _, _, cnt = jax.lax.fori_loop(1, max_iter, body, (cr, ci, cnt))
+    out_ref[...] = cnt if max_iter > 0 else jnp.zeros_like(cnt)
 
 
 def escape_counts(row0, n_rows: int, width: int, height: int,
-                  max_iter: int, *, tile_h: int = 8, interpret: bool = True):
-    assert n_rows % tile_h == 0
-    grid = (n_rows // tile_h,)
-    kernel = functools.partial(_mandel_kernel, width=width, height=height,
-                               tile_h=tile_h, max_iter=max_iter)
-    row0_arr = jnp.asarray([row0], jnp.int32)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((tile_h, width), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_rows, width), jnp.int32),
-        interpret=interpret,
-    )(row0_arr)
+                  max_iter: int, *, col0=0, n_cols: int = 0,
+                  tile_h: int = 8, tile_w: int = 512,
+                  interpret: Optional[bool] = None):
+    """Iteration counts for the pixel tile rows [row0, row0+n_rows) x cols
+    [col0, col0+n_cols) of a width x height image; n_cols=0 means the full
+    width.  ``row0``/``col0`` may be traced.  Ragged edges are computed on
+    whole tiles and cropped."""
+    n_cols = n_cols or width
+    tile_w = min(tile_w, -(-n_cols // 128) * 128)
+    Ho = -(-n_rows // tile_h) * tile_h
+    Wo = -(-n_cols // tile_w) * tile_w
+    xs, ys = pixel_centres(row0, Ho, col0, Wo, width, height)
+    out = pl.pallas_call(
+        functools.partial(_mandel_kernel, max_iter=max_iter),
+        grid=(Ho // tile_h, Wo // tile_w),
+        in_specs=[pl.BlockSpec((1, tile_w), lambda i, j: (0, j)),
+                  pl.BlockSpec((tile_h, 1), lambda i, j: (i, 0))],
+        out_specs=pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Ho, Wo), jnp.int32),
+        interpret=resolve_interpret(interpret),
+    )(xs.reshape(1, Wo), ys.reshape(Ho, 1))
+    return out[:n_rows, :n_cols]

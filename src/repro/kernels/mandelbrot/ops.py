@@ -1,14 +1,16 @@
-"""Mandelbrot op: jit'd wrapper + range-partitionable entry (lws=256 px
-rows... the paper's lws=256 work-items = 1 row-block of the 14336px image;
-we define 1 work-group = 1 pixel row block of 256/width... practically:
-one work-group = 2 rows at width 128 lanes per row-group; for simplicity
-1 work-group = 1 image row)."""
+"""Mandelbrot op: jit'd wrapper + range-partitionable entries.  One
+work-group = LWS image rows (the paper's lws=256 work-items become row
+blocks of the 14336px image).
+
+The image is computed from scalars alone, so the tile origin is placed on
+the caller's ``device`` explicitly: it is the only array argument, and it
+decides where the compiled kernel runs."""
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.mandelbrot import kernel as K
 from repro.kernels.mandelbrot import ref as R
@@ -17,39 +19,36 @@ LWS = 8            # rows per work-group (alignment unit for packets)
 MAX_ITER = 5000
 
 
-@partial(jax.jit, static_argnames=("n_rows", "width", "height", "max_iter",
-                                   "use_pallas", "interpret"))
-def _run(row0, *, n_rows: int, width: int, height: int, max_iter: int,
-         use_pallas: bool = False, interpret: bool = True):
-    if use_pallas:
-        return K.escape_counts(row0, n_rows, width, height, max_iter,
-                               interpret=interpret)
-    return R.escape_counts(row0, n_rows, width, height, max_iter)
+@partial(jax.jit, static_argnames=("n_rows", "n_cols", "width", "height",
+                                   "max_iter", "use_pallas"))
+def _run_tile(origin, *, n_rows: int, n_cols: int, width: int,
+              height: int, max_iter: int, use_pallas: bool):
+    fn = partial(K.escape_counts, interpret=False) if use_pallas \
+        else R.escape_counts
+    return fn(origin[0], n_rows, width, height, max_iter,
+              col0=origin[1], n_cols=n_cols)
+
+
+def run_region(row0: int, n_rows: int, col0: int, n_cols: int, *,
+               width: int, height: int, max_iter: int = MAX_ITER,
+               use_pallas: bool = False, device=None):
+    """Escape counts for the pixel tile [row0, row0+n_rows) x
+    [col0, col0+n_cols) (the NDRange entry, coordinates in pixels), run on
+    ``device`` (None: the default device).  ``use_pallas`` picks the
+    compiled Pallas kernel (TPU only) over the jnp path."""
+    origin = jax.device_put(np.asarray([row0, col0], np.int32), device)
+    return _run_tile(origin, n_rows=n_rows, n_cols=n_cols, width=width,
+                     height=height, max_iter=max_iter,
+                     use_pallas=use_pallas)
 
 
 def run_range(offset: int, size: int, *, width: int, height: int,
               max_iter: int = MAX_ITER, use_pallas: bool = False,
-              interpret: bool = True):
-    return _run(jnp.int32(offset * LWS), n_rows=size * LWS, width=width,
-                height=height, max_iter=max_iter, use_pallas=use_pallas,
-                interpret=interpret)
-
-
-@partial(jax.jit, static_argnames=("n_rows", "n_cols", "width", "height",
-                                   "max_iter"))
-def _run_tile(row0, col0, *, n_rows: int, n_cols: int, width: int,
-              height: int, max_iter: int):
-    return R.escape_counts(row0, n_rows, width, height, max_iter,
-                           col0=col0, n_cols=n_cols)
-
-
-def run_region(row0: int, n_rows: int, col0: int, n_cols: int, *,
-               width: int, height: int, max_iter: int = MAX_ITER):
-    """Escape counts for the pixel tile [row0, row0+n_rows) x
-    [col0, col0+n_cols) (the NDRange entry, coordinates in pixels)."""
-    return _run_tile(jnp.int32(row0), jnp.int32(col0), n_rows=n_rows,
-                     n_cols=n_cols, width=width, height=height,
-                     max_iter=max_iter)
+              device=None):
+    """Escape counts of work-groups [offset, offset+size): full rows."""
+    return run_region(offset * LWS, size * LWS, 0, width, width=width,
+                      height=height, max_iter=max_iter,
+                      use_pallas=use_pallas, device=device)
 
 
 def total_work(height: int) -> int:

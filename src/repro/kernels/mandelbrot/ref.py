@@ -10,14 +10,24 @@ X0, X1 = -2.25, 0.75
 Y0, Y1 = -1.5, 1.5
 
 
+def pixel_centres(row0, n_rows: int, col0, n_cols: int, width: int,
+                  height: int):
+    """c of the tile's pixel centres: real parts ``xs`` (n_cols,) and
+    imaginary parts ``ys`` (n_rows,).  The Pallas kernel takes them from
+    here too: Mosaic and XLA need not round this arithmetic alike, and one
+    ulp of c changes the escape count of pixels near the set's boundary."""
+    ys = Y0 + (Y1 - Y0) * (jnp.arange(n_rows) + row0 + 0.5) / height
+    xs = X0 + (X1 - X0) * (jnp.arange(n_cols) + col0 + 0.5) / width
+    return xs, ys
+
+
 def escape_counts(row0: int, n_rows: int, width: int, height: int,
                   max_iter: int, col0: int = 0, n_cols: int = 0):
     """Iteration counts for the pixel tile rows [row0, row0+n_rows) x
     cols [col0, col0+n_cols); n_cols=0 means the full width."""
     if not n_cols:
         n_cols = width
-    ys = Y0 + (Y1 - Y0) * (jnp.arange(n_rows) + row0 + 0.5) / height
-    xs = X0 + (X1 - X0) * (jnp.arange(n_cols) + col0 + 0.5) / width
+    xs, ys = pixel_centres(row0, n_rows, col0, n_cols, width, height)
     cr = jnp.broadcast_to(xs[None, :], (n_rows, n_cols))
     ci = jnp.broadcast_to(ys[:, None], (n_rows, n_cols))
 

@@ -22,33 +22,30 @@ def make_inputs(n_bodies: int, seed: int = 0):
     return np.concatenate([pos, mass], 1), vel
 
 
-@partial(jax.jit, static_argnames=("size", "use_pallas", "interpret"))
-def _run(pos_mass, vel, offset, *, size: int, use_pallas: bool = False,
-         interpret: bool = True):
-    if use_pallas:
-        tgt = jax.lax.dynamic_slice(pos_mass, (offset, 0), (size, 4))
-        acc = K.accelerations(tgt, pos_mass, tile_t=min(128, size),
-                              interpret=interpret)
-        v = jax.lax.dynamic_slice(vel, (offset, 0), (size, 3)) + acc * DT
-        p = tgt[:, :3] + v * DT
-        return jnp.concatenate([p, tgt[:, 3:], v], axis=1)
+@partial(jax.jit, static_argnames=("size", "use_pallas"))
+def _run(pos_mass, vel, offset, *, size: int, use_pallas: bool):
     tgt = jax.lax.dynamic_slice(pos_mass, (offset, 0), (size, 4))
-    src = pos_mass[:, :3]
-    m = pos_mass[:, 3]
-    d = src[None, :, :] - tgt[:, None, :3]
-    r2 = (d * d).sum(-1) + R.EPS2
-    inv_r3 = jax.lax.rsqrt(r2) / r2 * m[None, :]
-    acc = (d * inv_r3[..., None]).sum(axis=1)
+    if use_pallas:
+        acc = K.accelerations(tgt, pos_mass, interpret=False)
+    else:
+        # component-wise (T, N) planes: no (T, N, 3) intermediate, whose
+        # 3-wide minor dimension a TPU would pad to 128 lanes
+        d = [pos_mass[None, :, c] - tgt[:, c, None] for c in range(3)]
+        r2 = d[0] * d[0] + d[1] * d[1] + d[2] * d[2] + R.EPS2
+        inv_r3 = jax.lax.rsqrt(r2) / r2 * pos_mass[None, :, 3]
+        acc = jnp.stack([(dc * inv_r3).sum(axis=1) for dc in d], axis=1)
     v = jax.lax.dynamic_slice(vel, (offset, 0), (size, 3)) + acc * DT
     p = tgt[:, :3] + v * DT
     return jnp.concatenate([p, tgt[:, 3:], v], axis=1)
 
 
 def run_range(pos_mass, vel, offset: int, size: int, *,
-              use_pallas: bool = False, interpret: bool = True):
-    """Returns (size*LWS, 7) rows: [x,y,z,m,vx,vy,vz] after one step."""
-    return _run(pos_mass, vel, jnp.int32(offset * LWS), size=size * LWS,
-                use_pallas=use_pallas, interpret=interpret)
+              use_pallas: bool = False):
+    """Returns (size*LWS, 7) rows: [x,y,z,m,vx,vy,vz] after one step.
+    ``use_pallas`` picks the compiled Pallas kernel (TPU only) over the jnp
+    path."""
+    return _run(pos_mass, vel, offset * LWS, size=size * LWS,
+                use_pallas=use_pallas)
 
 
 def total_work(n_bodies: int) -> int:

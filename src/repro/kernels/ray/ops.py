@@ -5,7 +5,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.ray import ref as R
 
@@ -22,7 +21,7 @@ def _run(centers, radii, colors, row0, *, n_rows: int, width: int,
 def run_range(scene, offset: int, size: int, *, width: int, height: int,
               **_):
     return _run(scene["centers"], scene["radii"], scene["colors"],
-                jnp.int32(offset * LWS), n_rows=size * LWS, width=width,
+                offset * LWS, n_rows=size * LWS, width=width,
                 height=height)
 
 
@@ -39,7 +38,7 @@ def run_region(scene, row0: int, n_rows: int, col0: int, n_cols: int, *,
     """Render the pixel tile [row0, row0+n_rows) x [col0, col0+n_cols)
     -> (n_rows, n_cols, 3) (the NDRange entry, coordinates in pixels)."""
     return _run_tile(scene["centers"], scene["radii"], scene["colors"],
-                     jnp.int32(row0), jnp.int32(col0), n_rows=n_rows,
+                     row0, col0, n_rows=n_rows,
                      n_cols=n_cols, width=width, height=height)
 
 

@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import: jax locks the device
 # count on first init.  This process is the ONLY place that sees 512
-# placeholder devices; smoke tests and benches see the real single device.
+# placeholder devices, all on the host CPU (it never takes a chip);
+# smoke tests and benches see the real devices.
 
 import argparse          # noqa: E402
 import gzip              # noqa: E402
@@ -17,6 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.configs import (ARCH_IDS, SHAPES, get_config,  # noqa: E402
                            shapes_for)
 from repro.launch import hlo_analysis, hlo_cost  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch import specs as SP  # noqa: E402
 from repro.optim.adamw import OptConfig, TrainState  # noqa: E402
@@ -211,6 +214,7 @@ def main():
                     help="artifact suffix for perf iterations")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cells = cell_list() if args.all else [(args.arch, args.shape)]
     meshes = {"single": [False], "multi": [True],
               "both": [False, True]}[args.mesh]

@@ -25,6 +25,7 @@ from repro.configs.base import ShapeConfig
 from repro.core.device import DeviceGroup
 from repro.core.hetero_dp import HeteroDPTrainer
 from repro.data.pipeline import SyntheticPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim import adamw
 from repro.optim.adamw import OptConfig
@@ -60,6 +61,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     shape = ShapeConfig("cli", args.seq, args.batch, "train",
                         accum_steps=args.accum)

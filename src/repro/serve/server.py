@@ -110,7 +110,7 @@ class CoexecServer:
             policy=cfg.policy, gen=cfg.gen, min_gen=cfg.min_gen,
             round_quantum_s=cfg.round_quantum_s, unit_work=True))
         self.session = EngineSession(
-            [DeviceGroup(r.name,
+            [DeviceGroup(r.name, device=r.device,
                          power_model=cfg.power_models.get(r.name,
                                                           ZERO_POWER))
              for r in self.replicas],

@@ -25,7 +25,8 @@ from repro.parallel.sharding import ShardingResolver
 from repro.training import step as STEP
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke("llama3.2-1b")
 shape = ShapeConfig("t", 64, 8, "train", accum_steps=2)
 resolver = ShardingResolver(mesh, fsdp=True)
@@ -64,6 +65,7 @@ print(json.dumps({
 
 def test_small_mesh_dryrun_subprocess():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"        # the child must never take a chip
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
