@@ -49,6 +49,7 @@ import weakref
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro.core.clock import span
 from repro.core.device import DeviceGroup
 from repro.core.membuf import ArenaStats, BufferArena
 from repro.core.metrics import RunResult
@@ -78,6 +79,7 @@ class _Submission:
     journal: Optional[object] = None     # RunJournal for packet commits
     journal_key: Optional[str] = None
     handle: RunHandle = field(default=None)  # type: ignore[assignment]
+    enqueued: float = 0.0                # perf_counter() at enqueue
 
 
 class EngineSession:
@@ -546,6 +548,7 @@ class EngineSession:
                                    discard=lambda: self._discard(sub),
                                    deps=dep_list)
             self._seq += 1
+            sub.enqueued = time.perf_counter()
             self._pending.append(sub)
             self._issued.add(sub.handle)
             # graph-wide accounting: static dim-0 total until the run
@@ -656,6 +659,7 @@ class EngineSession:
         return self._graph.remaining()
 
     def _execute(self, sub: _Submission) -> RunResult:
+        queue_s = time.perf_counter() - sub.enqueued
         with self._lock:
             devices = [d for d in self._devices
                        if self.reset_device_stats or not d.dead]
@@ -701,12 +705,14 @@ class EngineSession:
                 self._tenant.end_run()
         else:
             result = ctx.execute()
+        result.queue_s = queue_s
         if sub.mode is OffloadMode.BINARY:
             # the binary contract tears down per submit: evict anything
             # cached under this name (stale earlier registrations included)
             # and charge the eviction to this run's teardown phase
             t0 = time.perf_counter()
-            self.evict(sub.program.name)
+            with span("coexec.teardown"):
+                self.evict(sub.program.name)
             extra = time.perf_counter() - t0
             if result.phases is not None:
                 result.phases = dataclasses.replace(

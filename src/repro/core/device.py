@@ -20,6 +20,7 @@ from typing import Any, Callable, Optional
 
 import jax
 
+from repro.core.clock import packet_compiles, span
 from repro.energy.model import ZERO_POWER, PowerModel
 
 
@@ -56,18 +57,26 @@ class DeviceGroup:
         return jax.device_put(x, self.device)
 
     def run_packet(self, fn: Callable, offset: int, size: int):
-        """Execute fn(offset, size); returns (result, wg_per_s)."""
+        """Execute fn(offset, size); returns (result, wg_per_s).  The
+        packet is a ``coexec.packet`` span: the host dispatch (slice, pad,
+        launch) is ``coexec.launch``, the wait for the device
+        ``coexec.wait``; jit lowerings inside it count against (this
+        group, ``size``)."""
         if (self.fail_after is not None
                 and self.packets_done >= self.fail_after):
             self.dead = True
             raise DeviceFailure(f"{self.name} failed (injected)")
-        t0 = time.perf_counter()
-        out = fn(offset, size)
-        out = jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        if self.throttle > 1.0:
-            time.sleep(dt * (self.throttle - 1.0))
-            dt *= self.throttle
+        with span("coexec.packet", group=self.name, size=size), \
+                packet_compiles(self.name, size):
+            t0 = time.perf_counter()
+            with span("coexec.launch"):
+                out = fn(offset, size)
+            with span("coexec.wait"):
+                out = jax.block_until_ready(out)
+            dt = time.perf_counter() - t0
+            if self.throttle > 1.0:
+                time.sleep(dt * (self.throttle - 1.0))
+                dt *= self.throttle
         self.packets_done += 1
         self.busy_time += dt
         wg_per_s = size / max(dt, 1e-9)

@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import enum
 import threading
-import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -525,10 +524,7 @@ class TransferPipeline:
     only pays above a staging-size crossover -- the same economics as a
     DMA engine.  Commits smaller than ``async_threshold_bytes`` run
     inline on the calling thread; larger ones go to the committer.
-
-    ``h2d_busy_s`` / ``d2h_busy_s`` accumulate the staging work the
-    pipeline handled (observability; the run's *phase* windows are
-    stamped by its PhaseClock).
+    ``commits`` and ``prefetches`` count the jobs the pipeline ran.
     """
 
     # hand-picked crossover for the reference container; sessions inject a
@@ -548,9 +544,7 @@ class TransferPipeline:
         self._closed = False
         self._draining = 0           # commits currently executing
         self._done_event: Optional[threading.Event] = None
-        self._time_lock = threading.Lock()
-        self.h2d_busy_s = 0.0
-        self.d2h_busy_s = 0.0
+        self._count_lock = threading.Lock()
         self.commits = 0
         self.prefetches = 0
 
@@ -559,22 +553,15 @@ class TransferPipeline:
         fut = StageFuture()
 
         def run():
-            t0 = time.perf_counter()
             try:
                 fut._set(fn(), None)
             except BaseException as e:  # surfaced at fut.result()
                 fut._set(None, e)
-            with self._time_lock:
-                self.h2d_busy_s += time.perf_counter() - t0
+            with self._count_lock:
                 self.prefetches += 1
 
         self._pool.submit(run)
         return fut
-
-    def note_h2d(self, seconds: float) -> None:
-        """Credit inline stage-in work (the unprefetched first packet)."""
-        with self._time_lock:
-            self.h2d_busy_s += seconds
 
     # -- stage-out ---------------------------------------------------------
     def start(self) -> None:
@@ -586,12 +573,10 @@ class TransferPipeline:
         threshold) run inline -- a thread wakeup would cost more than the
         copy it hides; large ones overlap on the committer thread."""
         if nbytes is not None and nbytes < self.async_threshold_bytes:
-            t0 = time.perf_counter()
             try:
                 fn()
             finally:
-                with self._time_lock:
-                    self.d2h_busy_s += time.perf_counter() - t0
+                with self._count_lock:
                     self.commits += 1
             return
         with self._cv:
@@ -609,12 +594,10 @@ class TransferPipeline:
                     return  # closed and drained
                 fn = self._jobs.popleft()
                 self._draining += 1
-            t0 = time.perf_counter()
             try:
                 fn()  # commit closures handle their own errors
             finally:
-                with self._time_lock:
-                    self.d2h_busy_s += time.perf_counter() - t0
+                with self._count_lock:
                     self.commits += 1
                 with self._cv:
                     self._draining -= 1

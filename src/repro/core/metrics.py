@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.energy.meter import EnergyReport
 
@@ -76,7 +76,7 @@ class RunResult:
     """Timing record of one co-execution run."""
     total_time: float                   # response time (ROI unless noted)
     device_busy: List[float]            # per-device busy time
-    device_finish: List[float]          # per-device finish timestamp
+    device_finish: List[float]          # end of each device's last packet
     packets: List                       # executed packets (scheduler.Packet)
     binary_time: Optional[float] = None  # incl. init/teardown ("binary" mode)
     aborted_devices: int = 0
@@ -90,6 +90,14 @@ class RunResult:
     # EnergyMeter.  None only when an executor predates the energy
     # subsystem; joule-blind (zero PowerModel) runs report total_j == 0.
     energy: Optional[EnergyReport] = None
+    # submit() to the run starting on a session thread (dispatcher wake-up,
+    # dependency wait, pool hand-off); 0 outside a session
+    queue_s: float = 0.0
+    # jit lowerings inside each packet: {(group name, packet size): count}
+    compiles: Dict[Tuple[str, int], int] = field(default_factory=dict)
+    # the run's coexec.commit steps (copy back, output write, journal),
+    # summed over packets and threads
+    commit_s: float = 0.0
 
     @property
     def energy_j(self) -> float:

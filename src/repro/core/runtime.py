@@ -36,7 +36,11 @@ The paper's two runtime optimizations remain real, independent code paths:
 Timing modes per the paper: ``binary`` (init -> teardown) and ``roi``
 (transfer + compute only) — both are measured per run as a
 :class:`repro.core.metrics.PhaseBreakdown` stamped by the run's
-:class:`PhaseClock` (one timing implementation for all phases).
+:class:`PhaseClock` (one timing implementation for all phases).  The
+same steps are profiler spans (``repro.core.clock.span``): on the
+runner, ``coexec.init`` / ``drain`` / ``d2h`` / ``teardown`` follow the
+phase marks; on each device thread, ``coexec.build``, ``pull``,
+``poll``, ``packet`` (with ``launch`` and ``wait``) and ``commit``.
 
 Work geometry: a Program's work is a :class:`repro.core.region.Region`
 (1-D or 2-D NDRange).  1-D range kernels keep the classic
@@ -70,53 +74,13 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.clock import PhaseClock, Tally, count_compiles, span
 from repro.core.device import DeviceFailure, DeviceGroup
 from repro.core.membuf import BufferArena, BufferPolicy, TransferPipeline
 from repro.core.metrics import PhaseBreakdown, RunResult
 from repro.core.region import Region
 from repro.core.scheduler import DeviceProfile, SchedulerBase, make_scheduler
 from repro.energy.meter import EnergyMeter
-
-
-class PhaseClock:
-    """Named wall-clock marks for one run's phase accounting.
-
-    The runtime's single timing implementation: every phase boundary is a
-    ``mark``; durations are read back with ``between``/``since``.  Unset
-    marks read as 0.0 so partial runs (e.g. scheduler construction
-    failures) never crash the accounting path.
-    """
-
-    def __init__(self):
-        self._t: Dict[str, float] = {}
-        self._once = threading.Lock()
-
-    def mark(self, name: str) -> float:
-        t = time.perf_counter()
-        self._t[name] = t
-        return t
-
-    def mark_once(self, name: str) -> float:
-        """Set ``name`` only if unset (first caller wins; thread-safe) —
-        e.g. the ROI mark stamped by whichever device computes first."""
-        with self._once:
-            t = self._t.get(name)
-            if t is None:
-                t = self.mark(name)
-            return t
-
-    def at(self, name: str) -> Optional[float]:
-        return self._t.get(name)
-
-    def since(self, name: str) -> float:
-        t = self._t.get(name)
-        return 0.0 if t is None else time.perf_counter() - t
-
-    def between(self, a: str, b: str) -> float:
-        ta, tb = self._t.get(a), self._t.get(b)
-        if ta is None or tb is None:
-            return 0.0
-        return max(0.0, tb - ta)
 
 
 @dataclass
@@ -365,7 +329,7 @@ class _RunContext:
 
     def execute(self) -> RunResult:
         clock = PhaseClock()
-        clock.mark("start")
+        clock.phase("start", opens="coexec.init")
         prog = self.program
         run_region = self.run_region
         n = len(self.devices)
@@ -373,7 +337,6 @@ class _RunContext:
             for d in self.devices:
                 d.packets_done = 0
                 d.busy_time = 0.0
-                d.finish_time = 0.0
                 d.dead = False
 
         output = None
@@ -413,6 +376,11 @@ class _RunContext:
         compiled_ev = threading.Event()
         fns: List[Optional[Callable]] = [None] * n
         t0_busy = [d.busy_time for d in self.devices]
+        # ROI-clock time each device thread left its loop (a dead device's
+        # powered window), and the jit lowerings of its packets
+        exit_s: List[float] = [0.0] * n
+        compiles: List[Dict] = [{} for _ in range(n)]
+        commit_time = Tally()
         if use_pipeline:
             pipe = TransferPipeline(self.pool, self.async_threshold_bytes)
             pipe.start()
@@ -439,19 +407,20 @@ class _RunContext:
             preemption) and reads as an empty pull — the loop's drained()
             protocol keeps the thread polling while work remains."""
             sched = sched_of(i)
-            if tenant is not None:
-                if not tenant.begin_packet(i):
-                    sched.reclaim_lease(i)
-                    return None
-                tb[i] = time.perf_counter()
-                pkt = (sched.acquire(i) if self.dispatch == "leased"
-                       else sched.next_packet(i))
-                if pkt is None:
-                    tenant.end_packet(i, 0, tb[i])
-                return pkt
-            if self.dispatch == "leased":
-                return sched.acquire(i)
-            return sched.next_packet(i)
+            with span("coexec.pull"):
+                if tenant is not None:
+                    if not tenant.begin_packet(i):
+                        sched.reclaim_lease(i)
+                        return None
+                    tb[i] = time.perf_counter()
+                    pkt = (sched.acquire(i) if self.dispatch == "leased"
+                           else sched.next_packet(i))
+                    if pkt is None:
+                        tenant.end_packet(i, 0, tb[i])
+                    return pkt
+                if self.dispatch == "leased":
+                    return sched.acquire(i)
+                return sched.next_packet(i)
 
         def tenant_end(i: int, wg: int) -> None:
             """Close device i's tenant packet window (wg=0: the packet was
@@ -463,7 +432,6 @@ class _RunContext:
         def fetch_and_stage(i: int, fn: Callable):
             """Stage-in for device ``i``: pull the next packet and bind its
             launch (the H2D window's host work)."""
-            t0 = time.perf_counter()
             pkt = pull(i)
             if pkt is None:
                 return None
@@ -478,8 +446,6 @@ class _RunContext:
                 sched_of(i).release(i)
                 tenant_end(i, 0)
                 raise
-            if pipe is not None:
-                pipe.note_h2d(time.perf_counter() - t0)
             return pkt, call
 
         def sched_of(i: int) -> SchedulerBase:
@@ -499,13 +465,18 @@ class _RunContext:
                                            rows)
 
         def make_commit(i, pkt, res):
+            group = self.devices[i].name
+
             def commit():
                 try:
-                    r0 = pkt.offset * prog.out_rows_per_wg
-                    r1 = (pkt.offset + pkt.size) * prog.out_rows_per_wg
-                    rows = np.asarray(res).reshape(r1 - r0, out_cols)
-                    output[r0:r1] = rows
-                    journal_commit(pkt, rows)
+                    with span("coexec.commit", group=group, size=pkt.size):
+                        t0 = time.perf_counter()
+                        r0 = pkt.offset * prog.out_rows_per_wg
+                        r1 = r0 + pkt.size * prog.out_rows_per_wg
+                        rows = np.asarray(res).reshape(r1 - r0, out_cols)
+                        output[r0:r1] = rows
+                        journal_commit(pkt, rows)
+                        commit_time.add(time.perf_counter() - t0)
                     executed_by[i].append(("pkt", pkt))
                 except Exception as e:
                     # host-side commit failure is fatal for the run: the
@@ -559,7 +530,8 @@ class _RunContext:
                     # work can still appear.
                     if sched.drained():
                         break
-                    time.sleep(1e-3)
+                    with span("coexec.poll"):
+                        time.sleep(1e-3)
                     continue
                 pkt_region = pkt.region if pkt.region is not None \
                     else run_region.row_panel(pkt.offset, pkt.size)
@@ -572,6 +544,7 @@ class _RunContext:
                 try:
                     res, wg_s = dev.run_packet(self._invoke(fn, pkt_region),
                                                pkt.offset, pkt.size)
+                    dev.finish_time = clock.since("roi")
                 except DeviceFailure:
                     sched.requeue(pkt)
                     sched.mark_dead(i)
@@ -594,23 +567,24 @@ class _RunContext:
                     sched.note_packet_latency(i, pkt.size / max(wg_s, 1e-9))
                     if hasattr(sched, "observe"):
                         sched.observe(i, wg_s)
-                    if self.collect is not None:
-                        with exec_lock:
-                            self.collect(pkt, res, dev)
-                        my_done.append(("pkt", pkt))
-                        sched.release(i)
-                        tenant_end(i, pkt.size)
-                        continue
-                    r0 = pkt.offset * prog.out_rows_per_wg
-                    r1 = (pkt.offset + pkt.size) * prog.out_rows_per_wg
-                    res = np.asarray(res).reshape(r1 - r0, out_cols)
-                    bytes_io[i] += res.nbytes         # result readback
-                    if self.registered_buffers:
-                        output[r0:r1] = res           # in-place commit
-                    else:
-                        my_done.append(("copy", r0, r1,
-                                        np.array(res, copy=True)))
-                    journal_commit(pkt, res)
+                    with span("coexec.commit", group=dev.name,
+                              size=pkt.size):
+                        t0 = time.perf_counter()
+                        if self.collect is not None:
+                            with exec_lock:
+                                self.collect(pkt, res, dev)
+                        else:
+                            r0 = pkt.offset * prog.out_rows_per_wg
+                            r1 = r0 + pkt.size * prog.out_rows_per_wg
+                            res = np.asarray(res).reshape(r1 - r0, out_cols)
+                            bytes_io[i] += res.nbytes     # result readback
+                            if self.registered_buffers:
+                                output[r0:r1] = res       # in-place commit
+                            else:
+                                my_done.append(("copy", r0, r1,
+                                                np.array(res, copy=True)))
+                            journal_commit(pkt, res)
+                        commit_time.add(time.perf_counter() - t0)
                     my_done.append(("pkt", pkt))
                     sched.release(i)
                     tenant_end(i, pkt.size)
@@ -660,7 +634,8 @@ class _RunContext:
                     # same exit protocol as the sync loop
                     if sched.drained():
                         break
-                    time.sleep(1e-3)
+                    with span("coexec.poll"):
+                        time.sleep(1e-3)
                     try:
                         staged = fetch_and_stage(i, fn)
                     except Exception as e:
@@ -674,6 +649,7 @@ class _RunContext:
                 mark_roi()
                 try:
                     res, wg_s = dev.run_packet(call, pkt.offset, pkt.size)
+                    dev.finish_time = clock.since("roi")
                 except DeviceFailure:
                     abort_pipelined(i, pkt, None)
                     break
@@ -703,12 +679,18 @@ class _RunContext:
                     abort_stage_in(e)
                     break
 
+        def build(i: int) -> None:
+            dev = self.devices[i]
+            with span("coexec.build", group=dev.name):
+                fns[i] = self.compile_fn(dev)
+
         def device_thread(i: int):
             dev = self.devices[i]
+            dev.finish_time = 0.0
             if self.parallel_init:
                 # parallel AOT compile, overlapped with Runtime's prep
                 try:
-                    fns[i] = self.compile_fn(dev)
+                    build(i)
                 except Exception as e:      # compile failure = dead device
                     dev.dead = True
                     with exec_lock:
@@ -721,11 +703,12 @@ class _RunContext:
             if fn is None:
                 sched.mark_dead(i)            # compile failed: release work
                 return
-            if use_pipeline:
-                device_loop_pipelined(i, dev, fn, sched)
-            else:
-                device_loop_sync(i, dev, fn, sched)
-            dev.finish_time = clock.since("roi") if clock.at("roi") else 0.0
+            with count_compiles(compiles[i]):
+                if use_pipeline:
+                    device_loop_pipelined(i, dev, fn, sched)
+                else:
+                    device_loop_sync(i, dev, fn, sched)
+            exit_s[i] = clock.since("roi")
 
         def start_threads() -> List[threading.Event]:
             return [self.pool.submit(_bind(device_thread, i))
@@ -766,18 +749,18 @@ class _RunContext:
                 # sequential: discovery+compile each device, then scheduler
                 for i, d in enumerate(self.devices):
                     try:
-                        fns[i] = self.compile_fn(d)
+                        build(i)
                     except Exception as e:
                         d.dead = True
                         errors.append(e)
                 state["sched"] = build_scheduler()
                 done_events = start_threads()
                 ready.wait()
-            clock.mark("compiled")
+            clock.phase("compiled", opens="coexec.drain")
             compiled_ev.set()
             for ev in done_events:
                 ev.wait()
-            clock.mark("drained")
+            clock.phase("drained", opens="coexec.d2h")
             roi_time = clock.between("roi", "drained")
             if pipe is not None:
                 # drain the commit tail: everything still on the committer
@@ -804,11 +787,12 @@ class _RunContext:
                         if item[0] == "copy":
                             _, r0, r1, arr = item
                             output[r0:r1] = arr
-            clock.mark("assembled")
+            clock.phase("assembled", opens="coexec.teardown")
             packets = [it[1] for done in executed_by for it in done
                        if it[0] == "pkt"]
-            clock.mark("end")
+            clock.phase("end")
         finally:
+            clock.close()
             if pipe is not None:
                 pipe.close()
             if tenant is not None and state["sched"] is not None:
@@ -832,7 +816,7 @@ class _RunContext:
         crossings = state["sched"].lock_crossings_by_device()
         meter = EnergyMeter()
         for i, d in enumerate(self.devices):
-            window = d.finish_time if d.dead else roi_time
+            window = exit_s[i] if d.dead else roi_time
             meter.add(d.name, d.power_model,
                       busy_s=min(max(run_busy[i], 0.0), window),
                       window_s=window, crossings=crossings[i],
@@ -847,6 +831,8 @@ class _RunContext:
             phases=phases,
             sched_wait_s=state["sched"].sched_wait_s(),
             energy=meter.report(),
+            compiles={k: v for c in compiles for k, v in c.items()},
+            commit_s=commit_time.total_s,
         )
         result.output = output  # type: ignore[attr-defined]
         return result

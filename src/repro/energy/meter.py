@@ -1,6 +1,6 @@
 """EnergyMeter: integrate per-device time windows into joules.
 
-The meter is the energy twin of :class:`repro.core.runtime.PhaseClock`:
+The meter is the energy twin of :class:`repro.core.clock.PhaseClock`:
 one accounting implementation shared by every executor.  Each device
 contributes a :class:`DeviceEnergy` sample — busy seconds, a powered
 window, lock crossings and bytes moved — and the report's totals are the

@@ -24,6 +24,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import resolve_interpret
 from repro.kernels.binomial.ref import tree_coefficients
 
+# names the kernel's custom call in the compiled program
+KERNEL_NAME = "binomial_tree"
+
 
 def _binomial_kernel(s0_ref, strike_ref, vdt_ref, pu_ref, pd_ref, disc_ref,
                      out_ref, *, steps: int, levels: int):
@@ -62,5 +65,6 @@ def price_options(s0, strike, t_years, *, steps: int = 254,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name=KERNEL_NAME,
     )(*(r.reshape(1, n) for r in rows))
     return out.reshape(n)

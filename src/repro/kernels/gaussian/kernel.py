@@ -24,6 +24,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 
+# names the kernel's custom call in the compiled program
+KERNEL_NAME = "gaussian_blur"
+
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
@@ -72,5 +75,6 @@ def blur_rows(img_padded, w1d, *, tile_h: int = 64, tile_w: int = 512,
         out_specs=pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Ho, Wo), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name=KERNEL_NAME,
     )(img, img, img, img, w1d)
     return out[:H, :W]

@@ -25,6 +25,9 @@ from jax.experimental import pallas as pl
 from repro.kernels import resolve_interpret
 from repro.kernels.mandelbrot.ref import pixel_centres
 
+# names the kernel's custom call in the compiled program
+KERNEL_NAME = "mandelbrot_escape"
+
 
 def _mandel_kernel(cr_ref, ci_ref, out_ref, *, max_iter: int):
     tile_h, tile_w = out_ref.shape
@@ -68,5 +71,6 @@ def escape_counts(row0, n_rows: int, width: int, height: int,
         out_specs=pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Ho, Wo), jnp.int32),
         interpret=resolve_interpret(interpret),
+        name=KERNEL_NAME,
     )(xs.reshape(1, Wo), ys.reshape(Ho, 1))
     return out[:n_rows, :n_cols]

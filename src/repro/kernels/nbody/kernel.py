@@ -21,6 +21,9 @@ from jax.experimental import pallas as pl
 from repro.kernels import resolve_interpret
 from repro.kernels.nbody.ref import EPS2
 
+# names the kernel's custom call in the compiled program
+KERNEL_NAME = "nbody_accel"
+
 
 def _nbody_kernel(tgt_ref, src_ref, out_ref):
     j = pl.program_id(1)
@@ -67,5 +70,6 @@ def accelerations(targets, sources, *, tile_t: int = 512, tile_s: int = 128,
         out_specs=pl.BlockSpec((3, tile_t), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((3, Tp), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name=KERNEL_NAME,
     )(tgt, src)
     return acc.T[:T]

@@ -14,9 +14,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.paper_suite import PAPER_SIZES
+from repro.kernels.binomial import kernel as binomial_kernel
 from repro.kernels.binomial import ops as binomial_ops
+from repro.kernels.gaussian import kernel as gaussian_kernel
 from repro.kernels.gaussian import ops as gaussian_ops
+from repro.kernels.mandelbrot import kernel as mandelbrot_kernel
 from repro.kernels.mandelbrot import ops as mandelbrot_ops
+from repro.kernels.nbody import kernel as nbody_kernel
 from repro.kernels.nbody import ops as nbody_ops
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
@@ -91,13 +95,22 @@ CASES = {
 }
 
 
+# each case's kernel names its pallas_call
+KERNEL_NAMES = {"gaussian": gaussian_kernel.KERNEL_NAME,
+                "binomial": binomial_kernel.KERNEL_NAME,
+                "mandelbrot": mandelbrot_kernel.KERNEL_NAME,
+                "nbody": nbody_kernel.KERNEL_NAME}
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_compiles_for_v5e(one_chip, case):
     specs, fn = CASES[case]()
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in specs]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert KERNEL_NAMES[case.split("-")[0]] in text
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
