@@ -53,12 +53,46 @@ def test_binomial_monotone_in_spot():
 
 
 # -------------------------------------------------------------- mandelbrot
-@pytest.mark.parametrize("w,h,iters", [(64, 64, 64), (128, 32, 200)])
-def test_mandelbrot_kernel(w, h, iters):
+# (width, height, max_iter, (row0, n_rows), (col0, n_cols), where); n_rows
+# 0 is the whole height, n_cols 0 the whole width; ``where`` names what the
+# window holds, checked on the reference.  Interpret mode runs
+# on XLA's CPU backend, which contracts multiply-adds into FMAs as its
+# fusions allow, so long orbits near the set's boundary round differently
+# from the jnp path's: the windows stay small, and the chip compares the
+# two paths at full size (chip_smoke.py)
+@pytest.mark.parametrize("w,h,iters,rows,cols,where", [
+    pytest.param(64, 64, 64, (0, 0), (0, 0), None, id="64-64-64"),
+    pytest.param(128, 32, 200, (0, 0), (0, 0), None, id="128-32-200"),
+    pytest.param(64, 64, 150, (0, 0), (0, 0), None, id="iters-not-chunked"),
+    pytest.param(64, 64, 1, (0, 0), (0, 0), None, id="one-iter"),
+    # the view's top-left corner: every pixel escapes in the first chunk
+    pytest.param(512, 512, 200, (0, 8), (0, 128), "outside",
+                 id="all-outside"),
+    # around c = -0.2 + 0i, inside the main cardioid: runs to max_iter
+    pytest.param(512, 512, 100, (248, 16), (288, 128), "inside",
+                 id="all-inside"),
+    # a ragged column window, as the mandelbrot2d ROI adapter asks for it
+    pytest.param(256, 256, 200, (96, 16), (40, 200), None,
+                 id="column-window"),
+    # c = -2 stays on the radius (z = -2, 2, 2, ...) while every other
+    # pixel of its tile escapes at once: it alone keeps the tile running
+    pytest.param(6, 1, 200, (0, 8), (-127, 128), "radius",
+                 id="on-the-radius"),
+])
+def test_mandelbrot_kernel(w, h, iters, rows, cols, where):
     from repro.kernels.mandelbrot import kernel as K, ref as R
-    ref = R.escape_counts(0, h, w, h, iters)
-    got = K.escape_counts(0, h, w, h, iters, tile_h=8, interpret=True)
-    assert (np.asarray(ref) == np.asarray(got)).all()
+    row0, n_rows = rows[0], rows[1] or h
+    col0, n_cols = cols
+    ref = np.asarray(R.escape_counts(row0, n_rows, w, h, iters, col0, n_cols))
+    got = K.escape_counts(row0, n_rows, w, h, iters, col0=col0,
+                          n_cols=n_cols, tile_h=8, interpret=True)
+    assert (ref == np.asarray(got)).all()
+    if where == "outside":
+        assert ref.max() < K.CHUNK
+    if where == "inside":
+        assert (ref == iters).all()
+    if where == "radius":
+        assert ref[0, -1] == iters and (ref == iters).sum() == 1
 
 
 def test_mandelbrot_interior_maxes_out():
