@@ -67,7 +67,7 @@ def _bench_kernels() -> int:
                                     height=256, max_iter=256))
     from repro.kernels.mandelbrot import kernel as mk
     pal = mk.escape_counts(0, m.LWS, 256, 256, 64)
-    ref = m.run_range(0, 1, width=256, height=256, max_iter=64)
+    ref, = m.run_range(0, 1, width=256, height=256, max_iter=64)
     ok = bool((pal == ref).all())
     rows.append(("kernel_mandelbrot", us, f"pallas_exact={ok}"))
 

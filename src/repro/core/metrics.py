@@ -98,6 +98,10 @@ class RunResult:
     # the run's coexec.commit steps (copy back, output write, journal),
     # summed over packets and threads
     commit_s: float = 0.0
+    # the same steps per device (a committer-thread commit counts against
+    # its packet's device); they sum to commit_s.  Empty outside the
+    # threaded engine
+    device_commit_s: List[float] = field(default_factory=list)
 
     @property
     def energy_j(self) -> float:

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.region import Region
-from repro.core.runtime import Program
+from repro.core.runtime import Program, RowPieces, write_rows
 from repro.kernels.binomial import ops as binomial_ops
 from repro.kernels.gaussian import ops as gaussian_ops
 from repro.kernels.mandelbrot import ops as mandelbrot_ops
@@ -138,15 +138,19 @@ def binomial_program(n_options: int = 65536, seed: int = 0) -> Program:
 
 
 def mandelbrot_program(px: int = 512, max_iter: int = 256) -> Program:
+    """Full-width row packets; each packet runs as its power-of-two pieces
+    (``mandelbrot_ops.run_range``), returned as ``RowPieces``, so a device
+    compiles a bounded set of kernel shapes whatever sizes the scheduler
+    carves."""
     G = mandelbrot_ops.total_work(px)
 
     def build(dev):
         use_pallas = _use_pallas(dev)
 
         def fn(offset, size):
-            return mandelbrot_ops.run_range(
+            return RowPieces(mandelbrot_ops.run_range(
                 offset, size, width=px, height=px, max_iter=max_iter,
-                use_pallas=use_pallas, device=dev.device)
+                use_pallas=use_pallas, device=dev.device))
         return fn
 
     return Program("mandelbrot", G, 1, build,
@@ -226,18 +230,15 @@ def reference_output(program_name: str, packet: Optional[int] = None,
     region = prog.work_region
     d0 = region.dims[0]
     step = packet or d0.size
-    parts = []
+    cols = (region.dims[1].size if region.ndim == 2 else 1) * prog.out_cols
+    out = np.empty((d0.size * prog.out_rows_per_wg, cols), prog.out_dtype)
     for off in range(d0.offset, d0.offset + d0.size, step):
         size = min(step, d0.offset + d0.size - off)
         if region.ndim == 2:
             d1 = region.dims[1]
-            out = fn(off, size, d1.offset, d1.size)
+            got = fn(off, size, d1.offset, d1.size)
         else:
-            out = fn(off, size)
-        parts.append(np.asarray(out).reshape(size * prog.out_rows_per_wg,
-                                             -1))
-    out = np.concatenate(parts)
-    if region.ndim == 2:
-        return out.reshape(d0.size * prog.out_rows_per_wg,
-                           region.dims[1].size * prog.out_cols)
-    return out.reshape(prog.total_work * prog.out_rows_per_wg, prog.out_cols)
+            got = fn(off, size)
+        r0 = (off - d0.offset) * prog.out_rows_per_wg
+        write_rows(got, out[r0:r0 + size * prog.out_rows_per_wg])
+    return out

@@ -72,6 +72,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.clock import PhaseClock, Tally, count_compiles, span
@@ -92,8 +93,8 @@ class Program:
     total_work: int = 0                   # dim-0 work-groups (mirrors region)
     lws: int = 1                          # dim-0 alignment unit (mirrors)
     # build(device_group) -> range executable:
-    #   1-D: fn(offset, size)                   -> np.ndarray
-    #   2-D: fn(row0, n_rows, col0, n_cols)     -> np.ndarray tile
+    #   1-D: fn(offset, size)                   -> array, or RowPieces
+    #   2-D: fn(row0, n_rows, col0, n_cols)     -> array tile
     build: Optional[Callable[[DeviceGroup], Callable[..., Any]]] = None
     # output row-width: result rows per dim-0 work-group (paper's "out
     # pattern"); for 2-D programs out_cols is per dim-1 work-item
@@ -380,7 +381,9 @@ class _RunContext:
         # powered window), and the jit lowerings of its packets
         exit_s: List[float] = [0.0] * n
         compiles: List[Dict] = [{} for _ in range(n)]
-        commit_time = Tally()
+        # each device's commit seconds; a committer-thread commit counts
+        # against its packet's device
+        commit_time = [Tally() for _ in range(n)]
         if use_pipeline:
             pipe = TransferPipeline(self.pool, self.async_threshold_bytes)
             pipe.start()
@@ -473,10 +476,9 @@ class _RunContext:
                         t0 = time.perf_counter()
                         r0 = pkt.offset * prog.out_rows_per_wg
                         r1 = r0 + pkt.size * prog.out_rows_per_wg
-                        rows = np.asarray(res).reshape(r1 - r0, out_cols)
-                        output[r0:r1] = rows
-                        journal_commit(pkt, rows)
-                        commit_time.add(time.perf_counter() - t0)
+                        write_rows(res, output[r0:r1])
+                        journal_commit(pkt, output[r0:r1])
+                        commit_time[i].add(time.perf_counter() - t0)
                     executed_by[i].append(("pkt", pkt))
                 except Exception as e:
                     # host-side commit failure is fatal for the run: the
@@ -576,15 +578,20 @@ class _RunContext:
                         else:
                             r0 = pkt.offset * prog.out_rows_per_wg
                             r1 = r0 + pkt.size * prog.out_rows_per_wg
-                            res = np.asarray(res).reshape(r1 - r0, out_cols)
-                            bytes_io[i] += res.nbytes     # result readback
                             if self.registered_buffers:
-                                output[r0:r1] = res       # in-place commit
+                                rows = output[r0:r1]      # in-place commit
                             else:
-                                my_done.append(("copy", r0, r1,
-                                                np.array(res, copy=True)))
-                            journal_commit(pkt, res)
-                        commit_time.add(time.perf_counter() - t0)
+                                rows = np.empty((r1 - r0, out_cols),
+                                                prog.out_dtype)
+                            # result readback
+                            bytes_io[i] += write_rows(res, rows)
+                            if not self.registered_buffers:
+                                my_done.append(("copy", r0, r1, rows))
+                            journal_commit(pkt, rows)
+                        # free the packet's device buffers before the
+                        # next packet computes
+                        res = None
+                        commit_time[i].add(time.perf_counter() - t0)
                     my_done.append(("pkt", pkt))
                     sched.release(i)
                     tenant_end(i, pkt.size)
@@ -832,10 +839,58 @@ class _RunContext:
             sched_wait_s=state["sched"].sched_wait_s(),
             energy=meter.report(),
             compiles={k: v for c in compiles for k, v in c.items()},
-            commit_s=commit_time.total_s,
+            commit_s=sum(t.total_s for t in commit_time),
+            device_commit_s=[t.total_s for t in commit_time],
         )
         result.output = output  # type: ignore[attr-defined]
         return result
+
+
+@jax.tree_util.register_pytree_node_class
+class RowPieces:
+    """One packet's result as row pieces in row order, which a 1-D range
+    function may return in place of one array: the commit copies each
+    piece straight into its own rows of the output (``write_rows``).
+    A pytree, so ``jax.block_until_ready`` waits on every piece; adding
+    a scalar adds it to every piece, as it would to one array."""
+
+    def __init__(self, pieces):
+        self.pieces = tuple(pieces)
+
+    def __iter__(self):
+        return iter(self.pieces)
+
+    def __len__(self) -> int:
+        return len(self.pieces)
+
+    def __add__(self, other) -> "RowPieces":
+        return RowPieces(p + other for p in self.pieces)
+
+    def tree_flatten(self):
+        return self.pieces, None
+
+    @classmethod
+    def tree_unflatten(cls, _, pieces) -> "RowPieces":
+        return cls(pieces)
+
+
+def write_rows(res: Any, rows: np.ndarray) -> int:
+    """Copy one packet's result back into ``rows``, its rows of the
+    output, and return the bytes read back.  ``res`` is one array, or
+    ``RowPieces``, each piece written straight into its own rows (never
+    joined on the host).  A result that does not fill ``rows`` exactly
+    raises ``ValueError``."""
+    parts = res.pieces if isinstance(res, RowPieces) else (res,)
+    if sum(np.size(p) for p in parts) != rows.size:
+        raise ValueError(f"a packet of {rows.shape} output rows got pieces "
+                         f"of {[np.shape(p) for p in parts]}")
+    r = nbytes = 0
+    for piece in parts:
+        got = np.asarray(piece).reshape(-1, rows.shape[1])
+        rows[r:r + len(got)] = got
+        r += len(got)
+        nbytes += got.nbytes
+    return nbytes
 
 
 def _bind(fn: Callable, i: int) -> Callable[[], None]:

@@ -32,7 +32,8 @@ for name in ("mandelbrot", "mandelbrot2d"):
 
         def run(*args):
             out = fn(*args)
-            seen[group.name] |= {str(d) for d in out.devices()}
+            for piece in jax.tree.leaves(out):
+                seen[group.name] |= {str(d) for d in piece.devices()}
             return out
         return run
 
