@@ -148,6 +148,11 @@ class RunHandle:
             if self._state != _PENDING:
                 return False
             self._state = _RUNNING
+            # only a pending handle can be discarded; dropping the hook
+            # breaks the handle -> hook -> submission -> handle cycle, so a
+            # dropped result (and its run output) is freed at once, not at
+            # the next garbage collection
+            self._discard = None
             return True
 
     def _set_result(self, result) -> None:

@@ -51,7 +51,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.clock import span
 from repro.core.device import DeviceGroup
-from repro.core.membuf import ArenaStats, BufferArena
+from repro.core.membuf import (ArenaStats, BufferArena, OutputFreeList,
+                               OutputStats)
 from repro.core.metrics import RunResult
 from repro.core.region import Region
 from repro.core.runtime import Program, WorkerPool, _RunContext
@@ -190,6 +191,10 @@ class EngineSession:
                                      ring=arena_ring, name=f"{name}-arena")
             self._pool = WorkerPool(name=name)
             self._owns_pool = True
+        # host outputs of non-pooled runs, reused once no one refers to
+        # them; one list per session, tenant or not, and kept across
+        # evict(): keyed by (shape, dtype), not by program
+        self.outputs = OutputFreeList()
 
         self._executables: Dict[Tuple[str, str], Callable] = {}
         self._buffer_registry: Dict[Tuple[str, str], int] = {}
@@ -266,6 +271,11 @@ class EngineSession:
     def arena_stats(self) -> ArenaStats:
         """Counters/gauges of the session's buffer arena."""
         return self.arena.stats
+
+    @property
+    def output_stats(self) -> OutputStats:
+        """Counters/gauges of the session's output free list."""
+        return self.outputs.stats
 
     # -- workload registry (ROI offloading) ----------------------------------
     @property
@@ -634,6 +644,8 @@ class EngineSession:
                     self._cv.notify_all()
             else:
                 self._pool.submit(self._runner(sub))
+            # hold no finished run's result (or output) while waiting
+            action = sub = None
 
     def _runner(self, sub: _Submission) -> Callable[[], None]:
         """Job body for one started node: feed predecessor results, run,
@@ -681,6 +693,7 @@ class EngineSession:
             pool=self._pool,
             buffer_policy=policy,
             arena=self.arena if policy.pooled else None,
+            outputs=self.outputs,
             parallel_init=self.parallel_init,
             reset_device_stats=self.reset_device_stats,
             powers=sub.powers,
@@ -723,14 +736,15 @@ class EngineSession:
 
     # -- lifecycle -----------------------------------------------------------
     def close(self) -> None:
-        """Drain the pending graph, release the arena, stop the pool — in
-        that order.  The dispatcher drains every pending submission in
-        topological order (dependents run after — or fail/cancel cleanly
-        with — their predecessors; no queued ``_Submission`` leaks), and
-        the graph must drain *before* the arena closes (an in-flight
-        pooled run acquires from it) and the arena must release its
-        entries *before* ``WorkerPool.close()`` — a close racing in-flight
-        submits must not leak arena entries behind a dead pool."""
+        """Drain the pending graph, release the arena and the output free
+        list, stop the pool — in that order.  The dispatcher drains every
+        pending submission in topological order (dependents run after —
+        or fail/cancel cleanly with — their predecessors; no queued
+        ``_Submission`` leaks), and the graph must drain *before* the
+        arena closes (an in-flight pooled run acquires from it) and the
+        arena must release its entries *before* ``WorkerPool.close()`` — a
+        close racing in-flight submits must not leak arena entries behind
+        a dead pool."""
         with self._cv:
             if self._closing:
                 return
@@ -744,6 +758,7 @@ class EngineSession:
             self.arbiter.unregister(self._tenant)
         else:
             self.arena.close()               # pooled buffers released
+        self.outputs.close()
         if self._owns_pool:
             self._pool.close()
 

@@ -24,6 +24,12 @@ optimizations as first-class, auditable objects:
   conversion + commit into the run output, the D2H window) drains on a
   committer thread -- so device threads never block on host staging.
 
+* :class:`OutputFreeList` -- the host outputs of runs that are neither
+  pooled nor ``collect`` runs (BINARY submits, ``coexec``, DAG nodes),
+  keyed by ``(shape, dtype)``.  A run takes an output no one refers to
+  any more, so its commits write into pages that are already mapped
+  instead of first-touching a fresh allocation.
+
 * :class:`BufferPolicy` -- the Runtime buffer-handling policy.  Grown from
   the paper's boolean ``opt_buffers`` into three named contracts (see the
   enum docstring); ``POOLED`` is the default for warm ROI submits.
@@ -33,10 +39,17 @@ into a recycled arena buffer.  It stays valid until the same workload's
 output ring cycles back around (``ring`` submits later); copy it if you
 need it past that.  This is exactly the device-buffer semantics the paper's
 runtime exposes -- reuse is what makes warm offloads cheap.
+
+**Result-lifetime contract (BINARY and every other non-pooled run):** the
+output is never aliased.  It is handed out again only once nothing outside
+the free list refers to it: a caller holding the result, a row slice or any
+other view of it, keeps it out of reuse.  The session keeps at most two
+idle outputs per ``(shape, dtype)`` until ``close()``.
 """
 from __future__ import annotations
 
 import enum
+import sys
 import threading
 from collections import deque
 from dataclasses import dataclass
@@ -50,6 +63,8 @@ __all__ = [
     "BufferArena",
     "BufferLease",
     "BufferPolicy",
+    "OutputFreeList",
+    "OutputStats",
     "StageFuture",
     "TransferPipeline",
 ]
@@ -479,6 +494,86 @@ class ArenaPartition:
         s = self.stats
         return (f"ArenaPartition({self.tenant!r}, entries={s.entries}, "
                 f"pooled={s.bytes_pooled}B, leased={s.bytes_leased}B)")
+
+
+# --------------------------------------------------------------------------
+# Output free list (non-pooled runs)
+# --------------------------------------------------------------------------
+
+
+def _refs(bufs: List[np.ndarray], j: int) -> int:
+    return sys.getrefcount(bufs[j])
+
+
+# what _refs reads for a buffer only its list refers to.  Every view of a
+# buffer (a slice, a reshape, np.asarray of it) points its ``.base`` at
+# the buffer, so a live view reads more
+_IDLE_REFS = _refs([np.empty(0)], 0)
+
+
+@dataclass
+class OutputStats:
+    """Counters snapshot (monotonic, then gauges)."""
+
+    hits: int = 0          # an idle output handed out again
+    misses: int = 0        # a fresh allocation
+    # gauges
+    idle: int = 0          # outputs on the list that no one refers to
+    bytes_held: int = 0    # their bytes
+
+
+class OutputFreeList:
+    """Per-session host outputs of non-pooled runs (see module docstring).
+
+    Thread-safe.  ``acquire`` hands out a buffer that nothing outside the
+    list refers to (a hit) or allocates one with ``np.empty`` (a miss) --
+    never zeroed, since a run's packets write every row or the run fails.
+    The list follows the last ``KEEP`` buffers of each ``(shape, dtype)``
+    it handed out, so at most ``KEEP`` are ever idle: two let a caller
+    hold the previous result while the next run writes.  A caller holding
+    more results than that keeps the older ones to itself.
+    """
+
+    KEEP = 2
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._by_key: Dict[Tuple, List[np.ndarray]] = {}
+        self._stats = OutputStats()
+
+    def acquire(self, shape, dtype) -> Tuple[np.ndarray, bool]:
+        """An output of ``shape`` and ``dtype``, and whether it is one
+        handed out before.  The caller's reference keeps it out of reuse
+        from the moment this returns."""
+        key = (tuple(int(s) for s in shape), np.dtype(dtype).str)
+        with self._lock:
+            bufs = self._by_key.setdefault(key, [])
+            for j in range(len(bufs)):
+                if _refs(bufs, j) <= _IDLE_REFS:
+                    bufs.append(bufs.pop(j))      # newest hand-out last
+                    self._stats.hits += 1
+                    return bufs[-1], True
+            buf = np.empty(key[0], dtype)
+            self._stats.misses += 1
+            bufs.append(buf)
+            del bufs[:-self.KEEP]                 # stop following the oldest
+            return buf, False
+
+    def close(self) -> None:
+        """Drop every buffer; holders keep theirs."""
+        with self._lock:
+            self._by_key.clear()
+
+    @property
+    def stats(self) -> OutputStats:
+        with self._lock:
+            s = OutputStats(self._stats.hits, self._stats.misses)
+            for bufs in self._by_key.values():
+                for j in range(len(bufs)):
+                    if _refs(bufs, j) <= _IDLE_REFS:
+                        s.idle += 1
+                        s.bytes_held += bufs[j].nbytes
+            return s
 
 
 # --------------------------------------------------------------------------

@@ -102,6 +102,9 @@ class RunResult:
     # its packet's device); they sum to commit_s.  Empty outside the
     # threaded engine
     device_commit_s: List[float] = field(default_factory=list)
+    # the run wrote into a host output an earlier run had (the session's
+    # output free list), not a fresh allocation
+    output_reused: bool = False
 
     @property
     def energy_j(self) -> float:

@@ -77,7 +77,8 @@ import numpy as np
 
 from repro.core.clock import PhaseClock, Tally, count_compiles, span
 from repro.core.device import DeviceFailure, DeviceGroup
-from repro.core.membuf import BufferArena, BufferPolicy, TransferPipeline
+from repro.core.membuf import (BufferArena, BufferPolicy, OutputFreeList,
+                                TransferPipeline)
 from repro.core.metrics import PhaseBreakdown, RunResult
 from repro.core.region import Region
 from repro.core.scheduler import DeviceProfile, SchedulerBase, make_scheduler
@@ -230,6 +231,10 @@ class _Worker:
                 # keeps a recycled worker alive for the next submit
                 pass
             finally:
+                # an idle worker must not keep the job's closures (and the
+                # run output they reach) alive: the session's output free
+                # list reuses an output only once nothing refers to it
+                job = fn = None
                 done.set()
                 self._pool._recycle(self)
 
@@ -251,6 +256,7 @@ class _RunContext:
                  registered_buffers: bool = True,
                  buffer_policy: Optional[BufferPolicy] = None,
                  arena: Optional[BufferArena] = None,
+                 outputs: OutputFreeList,
                  parallel_init: bool = True,
                  reset_device_stats: bool = True,
                  powers: Optional[List[float]] = None,
@@ -283,6 +289,7 @@ class _RunContext:
             else BufferPolicy.from_flag(registered_buffers)
         self.registered_buffers = self.buffer_policy.registered
         self.arena = arena
+        self.outputs = outputs
         self.parallel_init = parallel_init
         self.reset_device_stats = reset_device_stats
         self.powers = list(powers) if powers is not None else None
@@ -341,6 +348,7 @@ class _RunContext:
                 d.dead = False
 
         output = None
+        output_reused = False
         # output geometry follows the RUN's region (an ROI submit returns
         # just its sub-region, rows relative to the region start)
         out_cols = prog.out_cols if run_region.ndim == 1 \
@@ -357,7 +365,10 @@ class _RunContext:
                                             (out_rows, out_cols),
                                             prog.out_dtype).array
             else:
-                output = np.zeros((out_rows, out_cols), prog.out_dtype)
+                # otherwise an output no one refers to any more, its pages
+                # already mapped, or a fresh one: never zeroed, as above
+                output, output_reused = self.outputs.acquire(
+                    (out_rows, out_cols), prog.out_dtype)
         profiles = [DeviceProfile(d.name,
                                   (self.powers[i] if self.powers else
                                    (d.throughput or 1.0 / d.throttle)),
@@ -841,6 +852,7 @@ class _RunContext:
             compiles={k: v for c in compiles for k, v in c.items()},
             commit_s=sum(t.total_s for t in commit_time),
             device_commit_s=[t.total_s for t in commit_time],
+            output_reused=output_reused,
         )
         result.output = output  # type: ignore[attr-defined]
         return result

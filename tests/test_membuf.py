@@ -1,11 +1,15 @@
 """Memory-subsystem tests: BufferArena, TransferPipeline, POOLED runs.
 
 Covers the arena's ring/recycle/LRU contracts (including hypothesis-driven
-submit sequences), pooled-vs-per-packet output equality on every registered
-scheduler, the exact five-window phase identity, fault tolerance under the
-pipelined device loop, the simulator's overlap model, and the close()
-drain-then-release ordering regression.
+submit sequences), the output free list's reuse rule, pooled-vs-per-packet
+output equality on every registered scheduler, the exact five-window phase
+identity, fault tolerance under the pipelined device loop, the simulator's
+overlap model, and the close() drain-then-release ordering regression.
 """
+import sys
+import threading
+import time
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -20,7 +24,8 @@ from repro.api import (
 )
 from repro.core import programs as P
 from repro.core.device import DeviceGroup
-from repro.core.membuf import BufferArena, TransferPipeline, bucket_bytes
+from repro.core.membuf import (BufferArena, OutputFreeList, TransferPipeline,
+                               bucket_bytes)
 from repro.core.runtime import WorkerPool
 from repro.core.simulate import SimConfig, SimDevice, simulate
 
@@ -435,3 +440,113 @@ def test_close_is_idempotent_and_arena_closed():
     session.close()
     with pytest.raises(RuntimeError, match="closed"):
         session.arena.acquire("p", "host", (4,), np.float32)
+
+
+# --------------------------------------------------------- output free list
+
+
+def test_output_free_list_hands_a_dropped_buffer_out_again():
+    outs = OutputFreeList()
+    a, reused = outs.acquire((4, 8), np.float32)
+    assert not reused
+    where = a.ctypes.data
+    del a
+    b, reused = outs.acquire((4, 8), np.float32)
+    assert reused and b.ctypes.data == where
+    # another shape or dtype never shares it
+    c, reused_c = outs.acquire((4, 8), np.int32)
+    d, reused_d = outs.acquire((8, 4), np.float32)
+    assert not reused_c and not reused_d
+    s = outs.stats
+    assert (s.hits, s.misses) == (1, 3)
+    assert s.idle == 0 and s.bytes_held == 0
+
+
+class _Held:
+    """Stands for a RunResult: keeps the output as an attribute."""
+
+    def __init__(self, output):
+        self.output = output
+
+
+HOLDERS = {
+    "result": _Held,
+    "array": lambda a: a,
+    "row_slice": lambda a: a[1:3],
+    "reshaped": lambda a: a.reshape(-1),
+    "asarray_view": lambda a: np.asarray(a)[:, ::2],
+}
+
+
+@pytest.mark.parametrize("holder", sorted(HOLDERS))
+def test_output_free_list_never_hands_out_a_buffer_still_referred_to(holder):
+    outs = OutputFreeList()
+    a, _ = outs.acquire((4, 8), np.float32)
+    a[...] = 7.0
+    kept = HOLDERS[holder](a)
+    del a
+    b, reused = outs.acquire((4, 8), np.float32)
+    held = kept.output if isinstance(kept, _Held) else kept
+    assert not reused and not np.shares_memory(b, held)
+    b[...] = -1.0
+    assert np.all(held == 7.0)                   # never aliased
+    del kept, held
+    c, reused = outs.acquire((4, 8), np.float32)  # the first, now dropped
+    assert reused and not np.shares_memory(b, c)
+
+
+def test_output_free_list_concurrent_acquires_get_distinct_buffers():
+    outs = OutputFreeList()
+    for _ in range(2):                          # two idle buffers to race for
+        outs.acquire((64, 64), np.float32)
+    n = 16
+    got = [None] * n
+    start = threading.Barrier(n)
+
+    def take(i):
+        start.wait(timeout=10)
+        got[i] = outs.acquire((64, 64), np.float32)[0]
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=take, args=(i,))
+                   for i in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=10)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert len({b.ctypes.data for b in got}) == n
+    s = outs.stats
+    assert (s.hits, s.misses) == (2, n)
+
+
+def test_output_free_list_keeps_at_most_two_idle_per_shape():
+    outs = OutputFreeList()
+    held = [outs.acquire((16, 16), np.float32)[0] for _ in range(5)]
+    other = outs.acquire((16, 16), np.float64)[0]
+    del held, other
+    s = outs.stats
+    assert s.idle == OutputFreeList.KEEP + 1
+    assert s.bytes_held == 2 * 16 * 16 * 4 + 16 * 16 * 8
+
+
+def test_output_free_list_close_releases_every_buffer():
+    session = EngineSession(devices3())
+    prog = P.PROGRAMS["gaussian"](h=256, w=128)
+    res = session.submit(prog, mode=OffloadMode.BINARY).result()
+    del res
+    # the session's runner thread lets go of the result a moment after
+    # result() returned
+    t_end = time.monotonic() + 10
+    while session.output_stats.idle == 0 and time.monotonic() < t_end:
+        time.sleep(1e-3)
+    s = session.output_stats
+    assert (s.misses, s.idle) == (1, 1)
+    assert s.bytes_held == 256 * 128 * 4
+    session.close()
+    s = session.output_stats
+    assert s.idle == 0 and s.bytes_held == 0

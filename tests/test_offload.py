@@ -1,5 +1,7 @@
 """Offload modes through the session: BINARY vs ROI contracts, workload
 registration, sub-region submits, and the per-phase breakdown."""
+import time
+
 import numpy as np
 import pytest
 
@@ -128,6 +130,87 @@ def test_binary_refuses_registered_workload_then_evicts_plain_cache():
         assert len(session.executables) == 3
         session.submit(prog, mode=OffloadMode.BINARY).result()
         assert session.executables == {}            # teardown dropped it
+
+
+# BINARY outputs come from the session's free list: a dropped output is
+# written again in place, a held one never is
+BINARY_1D = {"gaussian": dict(h=1024, w=128),
+             "mandelbrot": dict(px=64, max_iter=64)}
+
+
+@pytest.fixture(scope="module")
+def binary_refs():
+    return {name: P.reference_output(name, **kw)
+            for name, kw in BINARY_1D.items()}
+
+
+def _binary(session, name):
+    prog = P.PROGRAMS[name](**BINARY_1D[name])
+    return session.submit(prog, mode=OffloadMode.BINARY).result()
+
+
+def _scribble(out):
+    """Dirty a dropped output, so a rerun must rewrite every row."""
+    out.view(np.uint8)[...] = 0xA5
+    return out.ctypes.data
+
+
+def wait_idle(session, timeout=10.0):
+    """Wait until a dropped output is idle: the session's runner thread
+    lets go of the result a moment after ``result()`` returned."""
+    t_end = time.monotonic() + timeout
+    while session.output_stats.idle == 0:
+        assert time.monotonic() < t_end, "a dropped output never went idle"
+        time.sleep(1e-3)
+
+
+@pytest.mark.parametrize("name", sorted(BINARY_1D))
+def test_binary_submit_writes_into_a_dropped_output(name, binary_refs):
+    with EngineSession(devices3()) as session:
+        first = _binary(session, name)
+        assert not first.output_reused
+        where = _scribble(first.output)
+        del first
+        wait_idle(session)
+        second = _binary(session, name)
+        assert second.output_reused
+        assert second.output.ctypes.data == where
+        np.testing.assert_array_equal(second.output, binary_refs[name])
+        assert session.executables == {}        # teardown still evicts
+        s = session.output_stats
+        assert (s.hits, s.misses) == (1, 1)
+
+
+@pytest.mark.parametrize("name", sorted(BINARY_1D))
+def test_binary_submit_never_writes_into_a_held_output(name, binary_refs):
+    with EngineSession(devices3()) as session:
+        first = _binary(session, name)
+        before = first.output.copy()
+        second = _binary(session, name)
+        assert not second.output_reused
+        assert not np.shares_memory(first.output, second.output)
+        assert first.output.tobytes() == before.tobytes()
+        np.testing.assert_array_equal(second.output, binary_refs[name])
+        rows = first.output[8:16]               # a slice alone still holds
+        del first
+        third = _binary(session, name)
+        assert not third.output_reused
+        assert rows.tobytes() == before[8:16].tobytes()
+
+
+@pytest.mark.parametrize("name", sorted(BINARY_1D))
+def test_device_death_on_a_reused_dirty_output_stays_exact(name,
+                                                            binary_refs):
+    devs = [DeviceGroup("flaky", throttle=1.5, fail_after=0),
+            DeviceGroup("igpu", throttle=2.0),
+            DeviceGroup("gpu", throttle=1.0)]
+    with EngineSession(devs, scheduler="static") as session:
+        where = _scribble(_binary(session, name).output)
+        wait_idle(session)
+        res = _binary(session, name)
+        assert res.output_reused and res.output.ctypes.data == where
+        assert res.aborted_devices == 1 and res.retries >= 1
+        np.testing.assert_array_equal(res.output, binary_refs[name])
 
 
 def test_roi_rejects_different_instance_under_same_name():
